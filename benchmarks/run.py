@@ -57,6 +57,9 @@ def main() -> None:
     if args.repeat < 1:
         ap.error("--repeat must be >= 1")
 
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     from benchmarks import (
         chaos,
         convergence,

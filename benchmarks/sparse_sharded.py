@@ -1,4 +1,4 @@
-"""Sharded matrix-free path vs single-host matfree on a host-local mesh.
+"""Sharded matrix-free path vs single-host matfree on a four-device mesh.
 
 ISSUE 5's tentpole: the blocked-ELL shards ride ``shard_map`` — one group
 of partition blocks per device — so sparse systems larger than any single
@@ -23,17 +23,19 @@ bench-smoke):
     epoch) and the tol-armed serving solve (the early-exit gate needs the
     global residual in-scan: n·k + k);
   * wall-clock — within 1.2x of the single-host matfree solve at equal J
-    (on a HOST-LOCAL mesh the collectives are memcpys; the gate bounds
+    (on a virtual CPU mesh the collectives are memcpys; the gate bounds
     the sharding overhead, it does not claim a CPU speedup).
 
-Multi-device CPU needs ``--xla_force_host_platform_device_count`` set
-before jax initializes, so ``run()`` executes the measurement in a
-subprocess (the harness process keeps its single device) and parses one
-JSON line back.
+On a TPU host the harness process already holds the chips, so ``run()``
+measures in process on the first four. On the CPU platform the mesh needs
+``--xla_force_host_platform_device_count`` set before jax initializes, so
+``run()`` executes the measurement in a ``JAX_PLATFORMS=cpu`` subprocess
+(the harness process keeps its single device) and parses one JSON line
+back.
 
 The batch width is k=32 — the coalesced-batch regime the sharded path
 exists to serve (SolveServer dispatches (m, k) batches; the n·k consensus
-collective is latency-bound on a host-local mesh, so a single-RHS solve
+collective is latency-bound on a virtual CPU mesh, so a single-RHS solve
 measures the barrier, not the path). Wall times are best-of-5 per path
 with the two paths' reps INTERLEAVED: 2-core CI runners swing 2x+ on
 scheduling noise alone, and interleaving keeps load drift from landing
@@ -110,8 +112,9 @@ def run_inprocess(quick: bool, num_rhs: int):
     import jax
 
     assert jax.device_count() >= DEVICES, (
-        f"need {DEVICES} devices, got {jax.device_count()} — run() sets "
-        "XLA_FLAGS in the subprocess; standalone use must export it"
+        f"need {DEVICES} devices, got {jax.device_count()} — a four-chip "
+        "host, or on the CPU run() (which starts a virtual-device "
+        "subprocess); standalone CPU use must export XLA_FLAGS"
     )
     from repro.core import prepare
     from repro.sparse import generate_schenk_like
@@ -228,9 +231,14 @@ def run_inprocess(quick: bool, num_rhs: int):
 
 
 def run(quick: bool = False, num_rhs: int = 32):
+    import jax
+
+    if jax.default_backend() != "cpu":  # this process holds the devices
+        return run_inprocess(quick=quick, num_rhs=num_rhs)
     from repro.launch.mesh import force_host_device_count
 
     env = force_host_device_count(DEVICES, dict(os.environ))
+    env["JAX_PLATFORMS"] = "cpu"
     env["PYTHONPATH"] = _SRC + os.pathsep + env.get("PYTHONPATH", "")
     cmd = [sys.executable, str(pathlib.Path(__file__).resolve()), "--json",
            "--rhs", str(num_rhs)] + (["--quick"] if quick else [])

@@ -1,93 +1,36 @@
-"""Version-compat shims over the jax API surface this codebase targets.
+"""Two jax spellings the solver needs that the plain API does not default to.
 
-The repo is written against the current jax API (``jax.shard_map``,
-``jax.sharding.AxisType`` / ``get_abstract_mesh`` / ``set_mesh``, dict-valued
-``Compiled.cost_analysis``); CI and the baked container may carry an older
-jax where those live under ``jax.experimental`` or do not exist.  Every
-cross-version touchpoint goes through this module so the rest of the code
-imports one spelling and the suite stays green on both sides.
+``jax.make_mesh`` builds Explicit-typed axes; the solvers want Auto axes
+(sharding propagated by the compiler). ``jax.shard_map`` checks
+replication statically; the matfree sharded solver needs that check off.
 """
 from __future__ import annotations
 
-import contextlib
-from typing import Any, Sequence
+from typing import Sequence
 
 import jax
-
-# --- shard_map -------------------------------------------------------------
-# jax >= 0.6 exposes jax.shard_map; older releases ship it as
-# jax.experimental.shard_map.shard_map with the same (mesh, in_specs,
-# out_specs) keyword signature.
-if hasattr(jax, "shard_map"):
-    shard_map = jax.shard_map
-else:  # pragma: no cover - exercised only on old jax
-    from jax.experimental.shard_map import shard_map  # type: ignore[no-redef]
+from jax.sharding import AxisType
 
 
 def shard_map_unchecked(f, *, mesh, in_specs, out_specs):
-    """``shard_map`` with static replication checking disabled.
+    """``jax.shard_map`` with static replication checking disabled.
 
     The matfree sharded solver runs ``lax.while_loop``s with SHARD-LOCAL
     stopping conditions (each device's inner CG exits on its own blocks'
-    residuals); several jax releases have no replication rule for ``while``
-    and require the check off. The flag is ``check_rep`` on older releases
-    and ``check_vma`` on newer ones — probe the signature once and pass
-    whichever exists (or neither, if a future jax drops the knob).
+    residuals), which the varying-manual-axes check cannot type.
     """
-    import inspect
-
-    params = inspect.signature(shard_map).parameters
-    kw = {}
-    for name in ("check_rep", "check_vma"):
-        if name in params:
-            kw[name] = False
-            break
-    return shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kw)
-
-
-def make_mesh(axis_shapes: Sequence[int], axis_names: Sequence[str]):
-    """``jax.make_mesh`` with Auto axis types when the installed jax has them.
-
-    ``axis_types`` only exists on newer jax (and Auto is its default there);
-    older jax builds the same mesh from the positional form.
-    """
-    try:
-        from jax.sharding import AxisType
-    except ImportError:
-        return jax.make_mesh(tuple(axis_shapes), tuple(axis_names))
-    return jax.make_mesh(
-        tuple(axis_shapes),
-        tuple(axis_names),
-        axis_types=(AxisType.Auto,) * len(tuple(axis_names)),
+    return jax.shard_map(
+        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=False
     )
 
 
-def get_abstract_mesh():
-    """The mesh active under ``set_mesh``/``use_mesh``, or None.
-
-    Older jax has no abstract-mesh tracking at all; returning None makes
-    every sharding-constraint helper a no-op, which is exactly the single
-    device CPU behaviour those helpers already promise.
-    """
-    get = getattr(jax.sharding, "get_abstract_mesh", None)
-    if get is None:
-        return None
-    return get()
-
-
-def use_mesh(mesh) -> contextlib.AbstractContextManager:
-    """``jax.sharding.set_mesh`` when available, else the Mesh's own context
-    manager (activates the same trace-time mesh on old jax)."""
-    set_mesh = getattr(jax.sharding, "set_mesh", None)
-    if set_mesh is not None:
-        return set_mesh(mesh)
-    return mesh
-
-
-def cost_analysis(compiled) -> dict[str, Any]:
-    """``Compiled.cost_analysis()`` as a flat dict on every jax version
-    (older releases return a one-element list of dicts)."""
-    cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0] if cost else {}
-    return dict(cost or {})
+def make_mesh(
+    axis_shapes: Sequence[int], axis_names: Sequence[str], devices=None
+):
+    """``jax.make_mesh`` with Auto axis types, over ``devices`` (default:
+    the first ``prod(axis_shapes)`` devices of the default backend)."""
+    names = tuple(axis_names)
+    return jax.make_mesh(
+        tuple(axis_shapes), names,
+        axis_types=(AxisType.Auto,) * len(names), devices=devices,
+    )

@@ -19,6 +19,8 @@ import jax.numpy as jnp
 from repro.core import consensus, projections
 from repro.core.partition import Partition
 
+_HIGHEST = jax.lax.Precision.HIGHEST  # full f32 (see repro.core.projections)
+
 
 @functools.partial(jax.jit, static_argnames=("mode",))
 def classical_factors(blocks: jnp.ndarray, mode: str):
@@ -30,7 +32,7 @@ def classical_factors(blocks: jnp.ndarray, mode: str):
 
 def initial_from_pinv(pinvs: jnp.ndarray, bvecs: jnp.ndarray) -> jnp.ndarray:
     """x_j(0) = A_j⁺ b_j for one RHS (J, p) or a batch (J, p, k)."""
-    return jnp.einsum("jnp,jp...->jn...", pinvs, bvecs)
+    return jnp.einsum("jnp,jp...->jn...", pinvs, bvecs, precision=_HIGHEST)
 
 
 @functools.partial(jax.jit, static_argnames=("mode",))
@@ -46,7 +48,9 @@ def setup_classical(blocks: jnp.ndarray, bvecs: jnp.ndarray, mode: str):
 
 def make_apply(Ps: jnp.ndarray):
     """Dense projector application, batched over a trailing RHS axis."""
-    return lambda v: jnp.einsum("jmn,jn...->jm...", Ps, v)
+    return lambda v: jnp.einsum(
+        "jmn,jn...->jm...", Ps, v, precision=_HIGHEST
+    )
 
 
 def solve_apc(

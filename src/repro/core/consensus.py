@@ -18,6 +18,8 @@ from typing import Callable
 import jax
 import jax.numpy as jnp
 
+_HIGHEST = jax.lax.Precision.HIGHEST  # full f32 (see repro.core.projections)
+
 
 def _match_rhs(bvecs: jnp.ndarray, x: jnp.ndarray) -> jnp.ndarray:
     """Broadcast unbatched (J, p) bvecs against batched (…, k) state."""
@@ -30,7 +32,9 @@ def block_residual_sq(blocks: jnp.ndarray, bvecs: jnp.ndarray, x: jnp.ndarray):
     """Global residual ||A x − b||² computed block-wise (no A reassembly).
 
     Scalar for x (n,); per-system vector (k,) for a batched x (n, k)."""
-    r = jnp.einsum("jpn,n...->jp...", blocks, x) - _match_rhs(bvecs, x)
+    r = jnp.einsum(
+        "jpn,n...->jp...", blocks, x, precision=_HIGHEST
+    ) - _match_rhs(bvecs, x)
     return jnp.sum(r * r, axis=(0, 1))
 
 
@@ -118,7 +122,9 @@ def run_consensus(
         if blocks is not None and bvecs is not None:
             if block_history:
                 r = (
-                    jnp.einsum("jpn,n...->jp...", blocks, xbar)
+                    jnp.einsum(
+                        "jpn,n...->jp...", blocks, xbar, precision=_HIGHEST
+                    )
                     - _match_rhs(bvecs, xbar)
                 )
                 per_block = jnp.sum(r * r, axis=1)  # (J,) or (J, k)

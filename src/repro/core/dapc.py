@@ -31,6 +31,8 @@ from jax.scipy.linalg import solve_triangular
 from repro.core import consensus, projections
 from repro.core.partition import Partition
 
+_HIGHEST = jax.lax.Precision.HIGHEST  # full f32 (see repro.core.projections)
+
 # observability for the prepare/solve split: how many times the QR setup
 # (the cost prepare() exists to amortize) actually ran in this process
 SETUP_STATS = {"qr_calls": 0}
@@ -80,10 +82,10 @@ def initial_from_factors(
     (J, p, k) → x0s (J, n, k).
     """
     if mode == "tall":
-        y = jnp.einsum("jpn,jp...->jn...", Ws, bvecs)  # Q1ᵀ b
+        y = jnp.einsum("jpn,jp...->jn...", Ws, bvecs, precision=_HIGHEST)  # Q1ᵀ b
         return jax.vmap(lambda r, yy: _trisolve(r, yy, False, use_kernels))(Rs, y)
     z = jax.vmap(lambda r, b: _trisolve(r.mT, b, True, use_kernels))(Rs, bvecs)
-    return jnp.einsum("jpn,jp...->jn...", Ws, z)  # Qᵀᵀ z = Q z
+    return jnp.einsum("jpn,jp...->jn...", Ws, z, precision=_HIGHEST)  # Qᵀᵀ z = Q z
 
 
 def setup_decomposed(
@@ -100,7 +102,9 @@ def make_apply(Ws: jnp.ndarray, materialize_p: bool, use_kernels: bool = False):
     difference — the batched form feeds the MXU with (p,n)×(n,k) matmuls."""
     if materialize_p:
         Ps = jax.vmap(projections.materialize)(Ws)  # paper-faithful dense P_j
-        return lambda v: jnp.einsum("jmn,jn...->jm...", Ps, v)
+        return lambda v: jnp.einsum(
+            "jmn,jn...->jm...", Ps, v, precision=_HIGHEST
+        )
     if use_kernels:
         from repro.kernels.project import ops as project_ops
 
@@ -113,7 +117,9 @@ def make_apply(Ws: jnp.ndarray, materialize_p: bool, use_kernels: bool = False):
 
         return lambda v: jax.vmap(project_one)(Ws, v)
     return lambda v: v - jnp.einsum(
-        "jpn,jp...->jn...", Ws, jnp.einsum("jpn,jn...->jp...", Ws, v)
+        "jpn,jp...->jn...", Ws,
+        jnp.einsum("jpn,jn...->jp...", Ws, v, precision=_HIGHEST),
+        precision=_HIGHEST,
     )
 
 

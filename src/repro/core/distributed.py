@@ -32,7 +32,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.compat import shard_map
 from repro.core.dapc import setup_decomposed
 from repro.core.apc import setup_classical
 
@@ -95,7 +94,7 @@ def solve_sharded(
     q = float(straggler_prob)
 
     @functools.partial(
-        shard_map,
+        jax.shard_map,
         mesh=mesh,
         in_specs=(spec_in, spec_in, P(None) if x_ref is not None else P()),
         out_specs=(P(), {"mse": P(), "residual_sq": P()} if x_ref is not None
@@ -201,7 +200,7 @@ def solve_sharded_2d(
         raise ValueError(f"n={n} not divisible by {col_axis}={col_shards}")
 
     @functools.partial(
-        shard_map,
+        jax.shard_map,
         mesh=mesh,
         in_specs=(
             P(block_axes, col_axis),

@@ -74,6 +74,7 @@ trailing epochs cost vector ops only.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import time
 
 import jax
@@ -88,6 +89,8 @@ from repro.core.spectra import (
 )
 from repro.sparse.bsr import DEFAULT_BLOCK_SHAPE, PartitionedBSR
 from repro.sparse.matrix import COOMatrix
+
+_HIGHEST = jax.lax.Precision.HIGHEST  # full f32 (see repro.core.projections)
 
 # matfree applies the SAME projection for classical and decomposed APC (the
 # two differ only in how the DENSE path factorizes it)
@@ -177,8 +180,9 @@ def _pcg_gram(
     return y, used, r
 
 
-def _gram_pinv(op: PartitionedBSR, dtype) -> jnp.ndarray:
-    """Per-block dense pseudo-inverse of the Gram shards, (J, p_pad, p_pad).
+def _gram_pinv(op: PartitionedBSR, dtype) -> np.ndarray:
+    """Per-block dense pseudo-inverse of the Gram shards, (J, p_pad, p_pad),
+    as a host array (the caller places it).
 
     Built host-side in float64 from the (near-diagonal) sparse Gram and
     restricted to the nonsingular sub-block (padding rows — and any exactly
@@ -210,7 +214,7 @@ def _gram_pinv(op: PartitionedBSR, dtype) -> jnp.ndarray:
                 G[np.ix_(live, live)], rcond=rcond, hermitian=True
             )
             out[j][np.ix_(live, live)] = sub
-    return jnp.asarray(out.astype(dtype))
+    return out.astype(dtype)
 
 
 def _local_block_mean(a: jnp.ndarray) -> jnp.ndarray:
@@ -240,7 +244,6 @@ def consensus_epochs(
     num_epochs: int,
     block_mean=_local_block_mean,
     reduce_sum=_identity,
-    iters_reduce=_identity,
     x0=None,  # (n, k) predicted solution, or masked pair ((n, k), (k,))
     block_history: bool = False,  # per-block residual diagnostics
 ):
@@ -248,7 +251,7 @@ def consensus_epochs(
 
     ``op``/``bvecs`` hold whatever set of partition blocks this caller owns
     — ALL J blocks on a single host, or one shard's J_loc blocks inside a
-    ``shard_map`` (repro.core.matfree_sharded). The three reduction hooks
+    ``shard_map`` (repro.core.matfree_sharded). The two reduction hooks
     are the only places global information enters:
 
       * ``block_mean`` — (J_loc, n, k) -> GLOBAL block mean (n, k). The
@@ -259,14 +262,12 @@ def consensus_epochs(
         use for the global residual (no ``tol``) may pass identity and
         collapse the emitted partials after the scan instead, dropping
         the epoch to ONE collective.
-      * ``iters_reduce`` — per-shard inner-CG depth counts -> global (k,).
-        Reporting only; the direct Gram path never calls a collective here
-        (its depth is the constant 1), and the PCG path pays one k-length
-        ``pmax`` per epoch for the ``history["inner_iters"]`` metric.
 
     Everything else — both Gram solvers, the fused tile pass, the balance
     permutation — is strictly block-local, which is what makes the sharded
-    epoch's collective payload exactly n·k + k.
+    epoch's collective payload at most n·k + k. The inner-CG depth counts
+    in ``history["inner_iters"]`` are this caller's own blocks'; a sharded
+    caller takes their max over shards after the scan.
 
     To keep that bound at ONE consensus collective, the global block mean
     ``q = mean_j x_j`` is carried through the scan: the end-of-epoch mean
@@ -328,13 +329,12 @@ def consensus_epochs(
     else:
         xq, u0 = None, bvecs
     if direct:
-        y0 = jnp.einsum("jqp,jpk->jqk", gram_inv, u0)
+        y0 = jnp.einsum("jqp,jpk->jqk", gram_inv, u0, precision=_HIGHEST)
         setup_iters, r0 = ones, jnp.zeros_like(bvecs)
     else:
         y0, setup_iters, r0 = _pcg_gram(
             op, u0, diag_inv, inner_iters, inner_tol, use_kernels,
         )
-        setup_iters = iters_reduce(setup_iters)
     x0s = op.rmatvec(y0, use_kernels)
     if xq is not None:
         x0s = x0s + xq
@@ -346,14 +346,13 @@ def consensus_epochs(
     def live_step(xs, xbar, q, w, z, ywarm, active):
         u = z - w  # A_j (x̄ − x_j)
         if direct:
-            y = jnp.einsum("jqp,jpk->jqk", gram_inv, u)
+            y = jnp.einsum("jqp,jpk->jqk", gram_inv, u, precision=_HIGHEST)
             used, r = ones, None
         else:
             y, used, r = _pcg_gram(
                 op, u, diag_inv, inner_iters, inner_tol, use_kernels,
                 warm=ywarm if warm_start else None, active=active,
             )
-            used = iters_reduce(used)
         # x̄⁺ = KNOWN − (ηγ/J)·Σ_j A_jᵀy_j in exact arithmetic, and KNOWN
         # needs no transpose product — so the epoch's two tile
         # contractions run in ONE fused pass. The trajectory itself stays
@@ -834,32 +833,10 @@ def prepare_matfree(
         partition = "uniform" if plan.kind == "uniform" else "cost_aware"
     if plan is not None and plan.kind == "uniform":
         plan = None  # uniform plans take the historical path exactly
-    op = PartitionedBSR.from_coo(
-        coo, num_blocks, block_shape, dtype,
-        with_transpose=use_kernels,  # only the Pallas path streams A_jᵀ tiles
-        with_gram=True,  # the inner-solve operator (near-diagonal, few % extra)
-        balance=balance,
-        plan=plan,
-    )
-    # relative-epsilon Jacobi clamp: padded rows stay 0, near-zero Gram
-    # diagonals are bounded instead of exploding (see jacobi_weights)
-    diag_inv = op.jacobi_weights()
-    block_gamma_w = block_eta_w = spectra = None
-    if dynamics == "per_block":
-        from repro.core import spectra as spectra_mod
-
-        spectra = spectra_mod.block_spectra_matfree(op)
-        block_gamma_w, block_eta_w = spectra_mod.derive_dynamics(spectra)
-    if gram_solver == "auto":
-        inv_bytes = num_blocks * op.p_pad * op.p_pad * dtype.itemsize
-        gram_solver = "direct" if inv_bytes <= DIRECT_GRAM_BYTES else "pcg"
-    gram_inv = _gram_pinv(op, dtype) if gram_solver == "direct" else None
-    if inner_iters is None:
-        inner_iters = min(op.p_pad, 32)
-
-    cls, placement_kw = MatrixFreePreparedSolver, {}
+    cls, placement_kw, place = MatrixFreePreparedSolver, {}, jnp.asarray
     if mesh is not None:
         from jax.sharding import NamedSharding, PartitionSpec
+
         from repro.core.matfree_sharded import (
             ShardedMatrixFreeSolver,
             mesh_block_devices,
@@ -872,13 +849,38 @@ def prepare_matfree(
                 f"num_blocks={num_blocks} not divisible over the "
                 f"{num_devices} devices of mesh axes {block_axes}"
             )
-        sharding = NamedSharding(mesh, PartitionSpec(block_axes))
-        op = op.place(mesh, block_axes)
-        diag_inv = jax.device_put(diag_inv, sharding)
-        if gram_inv is not None:
-            gram_inv = jax.device_put(gram_inv, sharding)
         cls = ShardedMatrixFreeSolver
         placement_kw = {"mesh": mesh, "block_axes": block_axes}
+        sharding = NamedSharding(mesh, PartitionSpec(block_axes))
+        place = functools.partial(jax.device_put, device=sharding)
+    # a mesh-bound operator is built in host memory and goes from there
+    # straight to its shards, so no device ever holds it whole; everything
+    # derived from it below is computed on the placed shards
+    op = PartitionedBSR.from_coo(
+        coo, num_blocks, block_shape, dtype,
+        with_transpose=use_kernels,  # only the Pallas path streams A_jᵀ tiles
+        with_gram=True,  # the inner-solve operator (near-diagonal, few % extra)
+        balance=balance,
+        plan=plan,
+        host=mesh is not None,
+    )
+    if mesh is not None:
+        op = op.place(mesh, block_axes)
+    # relative-epsilon Jacobi clamp: padded rows stay 0, near-zero Gram
+    # diagonals are bounded instead of exploding (see jacobi_weights)
+    diag_inv = place(op.jacobi_weights())
+    block_gamma_w = block_eta_w = spectra = None
+    if dynamics == "per_block":
+        from repro.core import spectra as spectra_mod
+
+        spectra = spectra_mod.block_spectra_matfree(op)
+        block_gamma_w, block_eta_w = spectra_mod.derive_dynamics(spectra)
+    if gram_solver == "auto":
+        inv_bytes = num_blocks * op.p_pad * op.p_pad * dtype.itemsize
+        gram_solver = "direct" if inv_bytes <= DIRECT_GRAM_BYTES else "pcg"
+    gram_inv = place(_gram_pinv(op, dtype)) if gram_solver == "direct" else None
+    if inner_iters is None:
+        inner_iters = min(op.p_pad, 32)
     jax.block_until_ready(diag_inv)
     setup_seconds = time.perf_counter() - t0
 

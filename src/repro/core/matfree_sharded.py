@@ -25,9 +25,9 @@ heavy linear algebra worker-local the same way):
     applies the per-block pseudo-inverses as a local einsum, ``"pcg"``
     iterates on the local sparse Gram shards with a shard-local stopping
     test (its ``while_loop`` trip count may differ per device — that is
-    why the program runs under ``shard_map_unchecked``). The PCG path
-    additionally pays one k-length ``pmax`` per epoch to report
-    ``history["inner_iters"]``.
+    why the program runs under ``shard_map_unchecked``). The PCG depth
+    counts of ``history["inner_iters"]`` are reporting too: each shard
+    emits its own, and the max over shards is taken after the scan.
 
 ``prepare(A, mode="matfree", mesh=...)`` builds one of these; the solve
 contract (``SolveResult``, batched RHS, per-column early exit, serving
@@ -145,10 +145,12 @@ class ShardedMatrixFreeSolver(MatrixFreePreparedSolver):
             # the k-length psum stays in the epoch.
             partial_resid = tol is None
             rs = sharded if partial_resid else P()
+            # the inner-CG depth counts are reporting only, tol or not:
+            # per-shard counts ride out the same way, max taken post-scan
             hist_spec = {
                 "residual_sq": rs,
-                "inner_iters": P(),
-                "initial": {"residual_sq": rs, "inner_iters": P()},
+                "inner_iters": sharded,
+                "initial": {"residual_sq": rs, "inner_iters": sharded},
             }
             if block_history:
                 # per-block rows are block-SHARDED by construction: each
@@ -181,7 +183,6 @@ class ShardedMatrixFreeSolver(MatrixFreePreparedSolver):
                         (lambda a: a) if partial_resid
                         else (lambda a: jax.lax.psum(a, red))
                     ),
-                    iters_reduce=lambda c: jax.lax.pmax(c, red),
                     x0=x0,
                     block_history=block_history,
                 )
@@ -193,31 +194,33 @@ class ShardedMatrixFreeSolver(MatrixFreePreparedSolver):
                 out_specs=(P(), hist_spec),
             )
 
-            if partial_resid:
-
-                def run_fn(op, diag_inv, gram_inv, bvecs, gamma, eta, ref,
-                           x0):
-                    xbar, hist = inner(
-                        op, diag_inv, gram_inv, bvecs, gamma, eta, ref, x0
-                    )
-                    # per-shard partials came back stacked on axis 0:
-                    # (D·E, k) / (D·k,) — collapse to the global residuals
-                    k = bvecs.shape[-1]
+            def run_fn(op, diag_inv, gram_inv, bvecs, gamma, eta, ref, x0):
+                xbar, hist = inner(
+                    op, diag_inv, gram_inv, bvecs, gamma, eta, ref, x0
+                )
+                # per-shard values came back stacked on axis 0: (D·E, k) /
+                # (D·k,) — collapse them to the global ones
+                k = bvecs.shape[-1]
+                initial = dict(hist["initial"])
+                hist["inner_iters"] = jnp.max(
+                    hist["inner_iters"].reshape(num_shards, num_epochs, k),
+                    axis=0,
+                )
+                initial["inner_iters"] = jnp.max(
+                    initial["inner_iters"].reshape(num_shards, k), axis=0
+                )
+                if partial_resid:
                     hist["residual_sq"] = jnp.sum(
                         hist["residual_sq"].reshape(
                             num_shards, num_epochs, k
                         ),
                         axis=0,
                     )
-                    initial = dict(hist["initial"])
                     initial["residual_sq"] = jnp.sum(
                         initial["residual_sq"].reshape(num_shards, k), axis=0
                     )
-                    hist["initial"] = initial
-                    return xbar, hist
-
-            else:
-                run_fn = inner
+                hist["initial"] = initial
+                return xbar, hist
 
             run = jax.jit(run_fn)
             self._jit_cache[key] = run

@@ -36,6 +36,8 @@ from repro.core.partition import (
 )
 from repro.sparse.matrix import COOMatrix
 
+_HIGHEST = jax.lax.Precision.HIGHEST  # full f32 (see repro.core.projections)
+
 METHODS = ("apc", "dapc", "dgd", "cgnr")
 
 # ``prepare(..., mode=...)`` accepts the dense block modes (tall/wide/auto)
@@ -430,7 +432,9 @@ class PreparedSolver:
                     xq, mk = x0 if isinstance(x0, tuple) else (x0, None)
                     if mk is not None:
                         xq = jnp.where(mk, xq, jnp.zeros((), xq.dtype))
-                    bv_eff = bvecs - jnp.einsum("jpn,n...->jp...", blocks, xq)
+                    bv_eff = bvecs - jnp.einsum(
+                        "jpn,n...->jp...", blocks, xq, precision=_HIGHEST
+                    )
                 else:
                     xq, bv_eff = None, bvecs
                 if self.method == "dapc":

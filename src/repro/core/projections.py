@@ -14,7 +14,12 @@ builds the dense ``P`` exactly as the paper's reference implementation does.
 """
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
+
+# full-f32 dots: a TPU's default runs an f32 dot as one bf16 pass (~3
+# significant digits), below what the consensus tolerances ask for
+_HIGHEST = jax.lax.Precision.HIGHEST
 
 
 def qr_factor(block: jnp.ndarray, mode: str) -> tuple[jnp.ndarray, jnp.ndarray]:
@@ -32,13 +37,15 @@ def qr_factor(block: jnp.ndarray, mode: str) -> tuple[jnp.ndarray, jnp.ndarray]:
 
 def apply_projection(W: jnp.ndarray, v: jnp.ndarray) -> jnp.ndarray:
     """Implicit ``(I − WᵀW) v`` — two tall-skinny matmuls, no n×n temp."""
-    return v - W.mT @ (W @ v) if v.ndim > 1 else v - (W.mT @ (W @ v))
+    return v - jnp.matmul(
+        W.mT, jnp.matmul(W, v, precision=_HIGHEST), precision=_HIGHEST
+    )
 
 
 def materialize(W: jnp.ndarray) -> jnp.ndarray:
     """Dense ``P = I − WᵀW`` (paper-faithful; O(n²) memory)."""
     n = W.shape[-1]
-    return jnp.eye(n, dtype=W.dtype) - W.mT @ W
+    return jnp.eye(n, dtype=W.dtype) - jnp.matmul(W.mT, W, precision=_HIGHEST)
 
 
 def classical_projection(block: jnp.ndarray, mode: str) -> jnp.ndarray:

@@ -73,6 +73,7 @@ def block_spectra_matfree(op, iters: int = 24) -> dict:
     present, rmatvec∘matvec otherwise. Padded rows have zero diagonal and
     stay pinned at zero, so the iteration lives in the real row space.
     """
+    import jax
     import jax.numpy as jnp
 
     diag = np.asarray(op.gram_diag(), np.float64)  # (J, p_pad)
@@ -82,7 +83,10 @@ def block_spectra_matfree(op, iters: int = 24) -> dict:
     rows = live.sum(axis=1).astype(np.float64)
     v0 = live * _ramp(p_pad)
     v0 /= np.maximum(np.linalg.norm(v0, axis=1, keepdims=True), 1e-300)
-    v = jnp.asarray(v0[..., None], op.fwd_data.dtype)
+    # laid out like the operator's blocks (sharded on a mesh-placed one)
+    v = jax.device_put(
+        v0[..., None].astype(op.fwd_data.dtype), op.fwd_data.sharding
+    )
     lam = np.zeros(J)
     for _ in range(iters):
         w = op.gram_mv(v)

@@ -27,8 +27,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro import compat
-
 SHARDING_RULES: dict[str, tuple[str, ...]] = {
     "batch": ("pod", "data"),
     "embed": ("data",),
@@ -148,13 +146,13 @@ def maybe_shard_activations(
         seq_axis = ACT_SEQ_AXIS
     """Sequence-parallel sharding constraint on a (B, S, D) residual stream.
 
-    Active only when lowering under ``jax.sharding.use_mesh`` (the launcher
+    Active only when lowering under ``jax.sharding.set_mesh`` (the launcher
     does this); a no-op in CPU tests. Sharding the scanned carry makes the
     remat-saved per-layer activations 1/model_ways the size — the difference
     between fitting and not fitting HBM for the big train cells (DESIGN.md
     §7, EXPERIMENTS.md §Perf)."""
-    mesh = compat.get_abstract_mesh()
-    if mesh is None or not mesh.axis_names or getattr(x, "ndim", 0) != 3:
+    mesh = jax.sharding.get_abstract_mesh()
+    if not mesh.axis_names or getattr(x, "ndim", 0) != 3:
         return x
     names = set(mesh.axis_names)
     ba = tuple(a for a in batch_axes if a in names)
@@ -176,8 +174,8 @@ def constrain(x, axes: tuple[str | None, ...], rules=None):
     Used inside blocks whose internal reshapes defeat SPMD propagation —
     e.g. the SSD (B,nc,L,H,P) chunk tensors must keep H on the ``model``
     axis or they silently replicate 16× (EXPERIMENTS.md §Perf, zamba2)."""
-    mesh = compat.get_abstract_mesh()
-    if mesh is None or not mesh.axis_names or getattr(x, "ndim", 0) != len(axes):
+    mesh = jax.sharding.get_abstract_mesh()
+    if not mesh.axis_names or getattr(x, "ndim", 0) != len(axes):
         return x
     spec = logical_to_spec(axes, x.shape, mesh, rules)
     if all(p is None for p in spec):

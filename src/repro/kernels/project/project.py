@@ -37,7 +37,8 @@ def _matvec_kernel(w_ref, x_ref, xbar_ref, u_ref):
 
     v = (xbar_ref[...] - x_ref[...]).astype(jnp.float32)
     u_ref[...] += jnp.dot(
-        w_ref[...].astype(jnp.float32), v, preferred_element_type=jnp.float32
+        w_ref[...].astype(jnp.float32), v,
+        precision=jax.lax.Precision.HIGHEST, preferred_element_type=jnp.float32,
     )
 
 
@@ -47,7 +48,7 @@ def _update_kernel(gamma, w_ref, x_ref, xbar_ref, u_ref, o_ref):
     v = xbar_ref[...].astype(jnp.float32) - x
     proj = jnp.dot(
         w_ref[...].astype(jnp.float32).T, u_ref[...],
-        preferred_element_type=jnp.float32,
+        precision=jax.lax.Precision.HIGHEST, preferred_element_type=jnp.float32,
     )
     o_ref[...] = (x + gamma * (v - proj)).astype(o_ref.dtype)
 

@@ -38,8 +38,8 @@ def trisolve(
     if pad:
         idx = jnp.arange(n, n_pad)
         r_p = r_p.at[idx, idx].set(1.0)
-    y_p = jnp.pad(y, (0, pad))[:, None]
+    y_p = jnp.pad(y, (0, pad))[None, :]
     out = _kernel.trisolve_padded(
         r_p, y_p, lower=lower, block=block, interpret=interpret
     )
-    return out[:n, 0].astype(y.dtype)
+    return out[0, :n].astype(y.dtype)

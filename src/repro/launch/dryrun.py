@@ -225,14 +225,14 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool, artifacts_dir: str,
     t0 = time.perf_counter()
     fn, args, shardings, donate = build_cell(cfg, shape, mesh)
     jitted = jax.jit(fn, in_shardings=shardings, donate_argnums=donate)
-    with compat.use_mesh(mesh):  # activates SP activation constraints
+    with jax.sharding.set_mesh(mesh):  # activates SP activation constraints
         lowered = jitted.lower(*args)
         t_lower = time.perf_counter() - t0
         compiled = lowered.compile()
         t_compile = time.perf_counter() - t0 - t_lower
 
     mem = compiled.memory_analysis()
-    cost = compat.cost_analysis(compiled)
+    cost = compiled.cost_analysis()
     hlo = compiled.as_text()
     coll = collective_bytes(hlo)
     num_devices = mesh.devices.size

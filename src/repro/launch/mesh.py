@@ -2,7 +2,7 @@
 
 FUNCTIONS, not module-level constants — importing this module touches no
 jax state at all (jax enters via deferred imports), so CLI drivers can
-parse arguments, adjust ``XLA_FLAGS`` (``force_host_device_count``), and
+parse arguments, adjust ``XLA_FLAGS`` (``reserve_mesh_devices``), and
 only then pull in the solver stack.
 """
 from __future__ import annotations
@@ -30,13 +30,10 @@ def force_host_device_count(devices: int, env=None):
     """Split the host CPU into ``devices`` XLA devices (appends
     ``--xla_force_host_platform_device_count`` to ``XLA_FLAGS``).
 
-    MUST take effect before jax initializes its backends — call it
-    straight after argument parsing, before importing anything that
-    imports jax. The shared bootstrap for every host-local-mesh CLI flag
-    (``launch.solve --mesh``, ``launch.serve_solver --mesh``) and for
-    subprocess environments (``benchmarks/sparse_sharded.py``): pass a
-    mapping via ``env`` to mutate that instead of ``os.environ``. Returns
-    the mutated mapping.
+    The flag reaches the CPU backend only, and only if it lands before
+    jax initializes its backends. Pass a mapping via ``env`` to mutate
+    that (a child process's environment) instead of ``os.environ``.
+    Returns the mutated mapping.
     """
     if env is None:
         env = os.environ
@@ -47,9 +44,32 @@ def force_host_device_count(devices: int, env=None):
     return env
 
 
-def make_host_local_mesh(devices: int):
-    """(devices,)-shaped ``("data",)`` mesh — the block-sharded layout the
-    sharded matfree path places its ELL shards over."""
+def reserve_mesh_devices(devices: int) -> None:
+    """The ``--mesh D`` bootstrap, called before anything imports jax.
+
+    On the CPU platform (``JAX_PLATFORMS=cpu``) the mesh needs ``devices``
+    virtual host devices, so this sets the flag. Anywhere else the mesh
+    spans the accelerator's real devices and nothing is set.
+    """
+    if os.environ.get("JAX_PLATFORMS", "").strip().lower() == "cpu":
+        force_host_device_count(devices)
+
+
+def make_block_mesh(devices: int):
+    """``(devices,)``-shaped ``("data",)`` mesh over the first ``devices``
+    devices of the default backend — the chips of a TPU host, or the
+    virtual devices ``reserve_mesh_devices`` made on the CPU. This is the
+    block-sharded layout the sharded matfree path places its ELL shards
+    over."""
+    import jax
+
     from repro import compat
 
-    return compat.make_mesh((devices,), ("data",))
+    found = jax.devices()
+    if len(found) < devices:
+        raise ValueError(
+            f"a {devices}-device mesh needs {devices} devices; jax found "
+            f"{len(found)} {found[0].platform} device(s) (on a CPU-only "
+            "host, run with JAX_PLATFORMS=cpu to get virtual devices)"
+        )
+    return compat.make_mesh((devices,), ("data",), devices=found[:devices])

@@ -59,10 +59,10 @@ def build_parser() -> argparse.ArgumentParser:
     ap.add_argument("--mesh", type=int, default=0, metavar="D",
                     help="serve through the SHARDED matfree path: pooled "
                          "systems prepare once block-sharded over a D-device "
-                         "host-local mesh and every coalesced (m, k) batch "
-                         "solves on the mesh (requires --mode matfree; sets "
-                         "--xla_force_host_platform_device_count before jax "
-                         "initializes)")
+                         "mesh (the first D chips on a TPU host, or D "
+                         "virtual devices under JAX_PLATFORMS=cpu) and every "
+                         "coalesced (m, k) batch solves on the mesh "
+                         "(requires --mode matfree)")
     ap.add_argument("--trace", default="poisson",
                     choices=("poisson", "drifting"),
                     help="poisson: independent one-shot requests; drifting: "
@@ -195,17 +195,17 @@ def main(argv=None) -> None:
                      f"--mesh {args.mesh} devices")
         # must land before jax initializes its backends — hence before
         # the repro.serving import below
-        from repro.launch.mesh import force_host_device_count
+        from repro.launch.mesh import reserve_mesh_devices
 
-        force_host_device_count(args.mesh)
+        reserve_mesh_devices(args.mesh)
 
     from repro.sparse import make_problem
 
     mesh = None
     if args.mesh:
-        from repro.launch.mesh import make_host_local_mesh
+        from repro.launch.mesh import make_block_mesh
 
-        mesh = make_host_local_mesh(args.mesh)
+        mesh = make_block_mesh(args.mesh)
 
     prob = make_problem(n=args.n, m=args.m, seed=args.seed, dtype=np.float32)
     rng = np.random.default_rng(args.seed + 1)
@@ -430,4 +430,7 @@ def _run_replay(args, prob, system, server_kwargs, rng, tracer) -> None:
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     main()

@@ -30,10 +30,10 @@ def main():
                     help="execution path: dense blocks, matrix-free sparse "
                          "operator, or auto (nnz/memory estimate)")
     ap.add_argument("--mesh", type=int, default=0, metavar="D",
-                    help="shard the matfree operator over a D-device "
-                         "host-local mesh (sets "
-                         "--xla_force_host_platform_device_count before jax "
-                         "initializes; requires --mode matfree)")
+                    help="shard the matfree operator over a D-device mesh: "
+                         "the first D chips on a TPU host, or D virtual "
+                         "devices under JAX_PLATFORMS=cpu (requires --mode "
+                         "matfree)")
     ap.add_argument("--implicit-p", action="store_true",
                     help="beyond-paper: never materialize the projector")
     ap.add_argument("--kernels", action="store_true",
@@ -48,9 +48,9 @@ def main():
                      f"{args.mesh} devices")
         # must land before jax initializes its backends — hence the
         # deferred repro/jax imports below
-        from repro.launch.mesh import force_host_device_count
+        from repro.launch.mesh import reserve_mesh_devices
 
-        force_host_device_count(args.mesh)
+        reserve_mesh_devices(args.mesh)
 
     import numpy as np
 
@@ -59,9 +59,9 @@ def main():
 
     mesh = None
     if args.mesh:
-        from repro.launch.mesh import make_host_local_mesh
+        from repro.launch.mesh import make_block_mesh
 
-        mesh = make_host_local_mesh(args.mesh)
+        mesh = make_block_mesh(args.mesh)
 
     prob = make_problem(n=args.n, m=args.m, seed=0, dtype=np.float32)
     kw = {}
@@ -99,4 +99,7 @@ def main():
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     main()

@@ -46,6 +46,8 @@ import numpy as np
 
 from repro.sparse.matrix import COOMatrix
 
+_HIGHEST = jax.lax.Precision.HIGHEST  # full f32 (see repro.core.projections)
+
 DEFAULT_BLOCK_SHAPE = (8, 8)
 
 
@@ -289,7 +291,7 @@ def _pad_cols(x: jnp.ndarray, n: int, bn: int) -> jnp.ndarray:
 def _ell_matmul(indices, data, xb):
     """One shard: indices (R, S), data (R, S, bp, bn), xb (C, bn, k)."""
     g = xb[indices]  # gather: (R, S, bn, k)
-    out = jnp.einsum("rspb,rsbk->rpk", data, g)
+    out = jnp.einsum("rspb,rsbk->rpk", data, g, precision=_HIGHEST)
     R, _, bp, _ = data.shape
     return out.reshape(R * bp, -1).astype(data.dtype)
 
@@ -307,7 +309,7 @@ def _ell_rmatmul(indices, data, yb, num_col_blocks):
     each tile contributes dataᵀ @ y_rowtile, scatter-added into its column
     block. Padding slots target block 0 with zero data — they add 0.
     """
-    contrib = jnp.einsum("rspb,rpk->rsbk", data, yb)
+    contrib = jnp.einsum("rspb,rpk->rsbk", data, yb, precision=_HIGHEST)
     C = num_col_blocks
     out = jnp.zeros((C, *contrib.shape[-2:]), data.dtype)
     out = out.at[indices].add(contrib)
@@ -343,8 +345,8 @@ def _ell_fused(indices, data, xb, yb, num_col_blocks):
     identical pair from one grid pass.
     """
     g = xb[indices]  # gather: (R, S, bn, k)
-    fwd = jnp.einsum("rspb,rsbk->rpk", data, g)
-    contrib = jnp.einsum("rspb,rpk->rsbk", data, yb)
+    fwd = jnp.einsum("rspb,rsbk->rpk", data, g, precision=_HIGHEST)
+    contrib = jnp.einsum("rspb,rpk->rsbk", data, yb, precision=_HIGHEST)
     R, _, bp, _ = data.shape
     return (
         fwd.reshape(R * bp, -1).astype(data.dtype),
@@ -397,7 +399,7 @@ def _gram_coo(rows: np.ndarray, cols: np.ndarray, vals: np.ndarray):
 
 
 def _stack_shards(shards: list[tuple[np.ndarray, np.ndarray]]):
-    """Pad per-shard ELL arrays to a common slot count and stack to device."""
+    """Pad per-shard ELL arrays to a common slot count and stack them."""
     S = max(idx.shape[1] for idx, _ in shards)
     J, R = len(shards), shards[0][0].shape[0]
     tile = shards[0][1].shape[-2:]
@@ -406,7 +408,7 @@ def _stack_shards(shards: list[tuple[np.ndarray, np.ndarray]]):
     for j, (idx, data) in enumerate(shards):
         idx_out[j, :, : idx.shape[1]] = idx
         data_out[j, :, : idx.shape[1]] = data
-    return jnp.asarray(idx_out), jnp.asarray(data_out)
+    return idx_out, data_out
 
 
 @dataclasses.dataclass(frozen=True)
@@ -485,6 +487,7 @@ class PartitionedBSR:
         with_gram: bool = False,
         balance: bool = False,
         plan=None,
+        host: bool = False,
     ) -> "PartitionedBSR":
         """Partition + convert, entirely without densifying.
 
@@ -505,6 +508,10 @@ class PartitionedBSR:
         is untouched). A planned operator's ``block_rhs`` is plan-order;
         use the owning solver's plan-aware ``block_rhs`` for original-order
         right-hand sides.
+
+        ``host=True`` leaves every array in host memory (numpy), for
+        ``place`` to send straight to its mesh shards; by default they are
+        device arrays.
         """
         m, n = coo.shape
         bp, bn = block_shape
@@ -559,20 +566,26 @@ class PartitionedBSR:
                 ), axis=1,
             )
             tile_local = int_np[blk, local].astype(np.int64)
-            ext_pos, int_pos = jnp.asarray(ext_np), jnp.asarray(int_np)
+            ext_pos, int_pos = ext_np, int_np
 
         # global padded layout: block j owns rows [j*p_pad, j*p_pad + p_pad)
         padded = COOMatrix(
             (blk * p_pad + tile_local).astype(np.int64), cols, coo.vals,
             (J * p_pad, n),
         )
-        full = BlockEll.from_coo(padded, block_shape, dtype)
+        full = BlockEll(
+            *_ell_arrays(
+                padded.rows, padded.cols, padded.vals, J * p_pad, n, bp, bn,
+                dtype,
+            ),
+            padded.shape,
+        )
         shards = [
             full.slice_row_blocks(j * p_pad, (j + 1) * p_pad) for j in range(J)
         ]
         # shards of one parent share S, so they stack without re-padding
-        fwd_idx = jnp.stack([s.indices for s in shards])
-        fwd_data = jnp.stack([s.data for s in shards])
+        fwd_idx = np.stack([s.indices for s in shards])
+        fwd_data = np.stack([s.data for s in shards])
 
         tra_idx = tra_data = None
         if with_transpose:
@@ -602,12 +615,13 @@ class PartitionedBSR:
                 ]
             )
 
-        return PartitionedBSR(
+        op = PartitionedBSR(
             fwd_idx, fwd_data, (m, n), p, p_pad,
             tra_indices=tra_idx, tra_data=tra_data,
             gram_indices=gram_idx, gram_data=gram_data,
             ext_pos=ext_pos, int_pos=int_pos, planned=use_plan,
         )
+        return op if host else jax.tree.map(jnp.asarray, op)
 
     # -- mesh placement ------------------------------------------------------
 
@@ -763,7 +777,7 @@ class PartitionedBSR:
         sq = sq.reshape(self.num_blocks, self.p_pad)
         if self.int_pos is None:
             return sq
-        return sq[jnp.arange(self.num_blocks)[:, None], self.int_pos]
+        return jnp.take_along_axis(sq, self.int_pos, axis=1)
 
     def jacobi_weights(self, eps: float = 1e-10) -> jnp.ndarray:
         """Inverse Gram diagonal (J, p_pad, 1), the inner-CG Jacobi weights.

@@ -86,9 +86,17 @@ def make_problem(
     sparsity: float = 0.9985,
     seed: int = 0,
     dtype=np.float64,
+    cond_boost: float = 1.0,
 ) -> Problem:
-    """Full pipeline: sparse square core -> true solution -> augmented system."""
-    coo = generate_schenk_like(n, sparsity=sparsity, seed=seed)
+    """Full pipeline: sparse square core -> true solution -> augmented system.
+
+    ``cond_boost`` scales the core's diagonal ridge (see
+    ``generate_schenk_like``); the augmentation keeps the core's condition
+    number, so this is what bounds how close an f32 solve can get to
+    ``x_true``."""
+    coo = generate_schenk_like(
+        n, sparsity=sparsity, seed=seed, cond_boost=cond_boost
+    )
     A_sq = coo.to_dense().astype(dtype)
     rng = np.random.default_rng(seed + 7)
     x_true = rng.standard_normal(n).astype(dtype)

@@ -10,7 +10,6 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-from repro import compat
 from repro.configs.base import ModelConfig
 from repro.models import costs, transformer
 
@@ -33,7 +32,7 @@ def _compiled_flops(cfg, b, s):
     )
     toks = jax.ShapeDtypeStruct((b, s), jnp.int32)
     c = jax.jit(_fwd_only(cfg)).lower(params, toks).compile()
-    return float(compat.cost_analysis(c)["flops"])
+    return float(c.cost_analysis()["flops"])
 
 
 CASES = [
@@ -72,9 +71,10 @@ def test_scan_undercount_demonstrated():
 
     x = jax.ShapeDtypeStruct((128, 128), jnp.float32)
     ws = jax.ShapeDtypeStruct((8, 128, 128), jnp.float32)
-    scanned = compat.cost_analysis(
-        jax.jit(lambda x, ws: jax.lax.scan(body, x, ws)[0]).lower(x, ws).compile()
-    )["flops"]
+    scanned = (
+        jax.jit(lambda x, ws: jax.lax.scan(body, x, ws)[0])
+        .lower(x, ws).compile().cost_analysis()["flops"]
+    )
     assert scanned < 8 * 2 * 128**3 / 2  # counts ~1 body, not 8
 
 

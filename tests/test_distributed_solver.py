@@ -251,7 +251,7 @@ STRAGGLER_RNG_SCRIPT = textwrap.dedent(
     import functools
     import jax, jax.numpy as jnp, numpy as np
     from jax.sharding import PartitionSpec as P
-    from repro.compat import shard_map
+    shard_map = jax.shard_map
     from repro.core import distributed
 
     # the failure mode: with block_axes=("pod", "data"), every shard that

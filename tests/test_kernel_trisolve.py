@@ -66,7 +66,7 @@ def test_vmapped_over_blocks():
 
 
 def test_f64_when_enabled():
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         rng = np.random.default_rng(0)
         n = 96
         r = np.triu(rng.standard_normal((n, n))) + np.eye(n) * 4.0
