@@ -87,6 +87,7 @@ from repro.core.spectra import (
     dynamics_meta as _dynamics_meta,
     dynamics_state as _dynamics_state,
 )
+from repro.obs.trace import span
 from repro.sparse.bsr import DEFAULT_BLOCK_SHAPE, PartitionedBSR
 from repro.sparse.matrix import COOMatrix
 
@@ -441,6 +442,30 @@ def consensus_epochs(
     return xbar, hist
 
 
+def live_epochs(hist: dict, num_epochs: int, tol: float | None) -> int:
+    """Epochs in which ``consensus_epochs`` ran its epoch body, counted on
+    the host from the history a solve returns.
+
+    Without ``tol`` every epoch is live. With it, epoch t runs the body iff
+    some column's residual at the epoch's start exceeds ``tol²`` — the
+    program's own freeze predicate, applied to the very residuals it
+    emitted (entry t − 1 of ``residual_sq``, the initial one for t = 0) in
+    their own dtype. The frozen branch also writes zero inner depths, but
+    a live PCG epoch whose warm-started inner solves start below their
+    tolerance reports zero depth too, so ``inner_iters`` alone would
+    undercount.
+    """
+    if tol is None:
+        return num_epochs
+    resid = np.asarray(hist["residual_sq"])
+    start = np.concatenate([
+        np.reshape(hist["initial"]["residual_sq"], (1, -1)),
+        np.reshape(resid, (num_epochs, -1))[:-1],
+    ])
+    threshold = start.dtype.type(float(tol) ** 2)
+    return int((start > threshold).any(axis=1).sum())
+
+
 @dataclasses.dataclass
 class MatrixFreePreparedSolver:
     """Sparse-operator counterpart of ``PreparedSolver``.
@@ -654,47 +679,58 @@ class MatrixFreePreparedSolver:
         per_block = self._resolve_dynamics(dynamics)
         b = np.asarray(b)
         batched = b.ndim == 2
-        bvecs = self.block_rhs(b)  # (J, p_pad, k) — k=1 for a single RHS
         dtype = self.op.fwd_data.dtype
-        ref = None if x_ref is None else jnp.asarray(x_ref, dtype)
-        warm = self._warm_operand(x0, batched, dtype)
-        gamma_op, eta_op = self._dynamics_operands(gamma, eta, dtype, per_block)
-
-        t0 = time.perf_counter()
-        run = self._solve_program(
-            num_epochs, inner_iters, ref is not None,
-            None if tol is None else float(tol),
-            warm_kind=None if warm is None else (
-                "masked" if isinstance(warm, tuple) else "x0"
-            ),
-            block_history=bool(block_history),
-            per_block=per_block,
-        )
-        x, hist = run(
-            self.op, self.diag_inv, self.gram_inv, bvecs,
-            gamma_op, eta_op, ref, warm,
-        )
-        x = jax.block_until_ready(x)
-        wall = time.perf_counter() - t0
+        tol = None if tol is None else float(tol)
+        with span(
+            "solve", path=self.path, k=b.shape[1] if batched else 1,
+            num_epochs=num_epochs,
+        ) as solve_span:
+            with span("solve.rhs"):
+                bvecs = self.block_rhs(b)  # (J, p_pad, k); k=1 for one RHS
+                ref = None if x_ref is None else jnp.asarray(x_ref, dtype)
+                warm = self._warm_operand(x0, batched, dtype)
+                gamma_op, eta_op = self._dynamics_operands(
+                    gamma, eta, dtype, per_block
+                )
+            with span("solve.run") as run_span:
+                run = self._solve_program(
+                    num_epochs, inner_iters, ref is not None, tol,
+                    warm_kind=None if warm is None else (
+                        "masked" if isinstance(warm, tuple) else "x0"
+                    ),
+                    block_history=bool(block_history),
+                    per_block=per_block,
+                )
+                x, hist = run(
+                    self.op, self.diag_inv, self.gram_inv, bvecs,
+                    gamma_op, eta_op, ref, warm,
+                )
+                x = jax.block_until_ready(x)
+            with span("solve.fetch"):
+                x = np.asarray(x)
+                hist = jax.tree.map(np.asarray, hist)
+                if not batched:  # collapse the internal k=1 axis like dense
+                    x = x[:, 0]
+                    hist = jax.tree.map(
+                        lambda a: a[..., 0] if a.ndim and a.shape[-1] == 1
+                        else a, hist,
+                    )
+            epochs_run = live_epochs(hist, num_epochs, tol)
+            solve_span.set(epochs_run=epochs_run)
         self.num_solves += 1
 
-        hist = jax.tree.map(np.asarray, hist)
-        if not batched:  # collapse the internal k=1 axis like the dense path
-            x = x[:, 0]
-            hist = jax.tree.map(
-                lambda a: a[..., 0] if a.ndim and a.shape[-1] == 1 else a, hist
-            )
         return SolveResult(
-            x=np.asarray(x),
+            x=x,
             method=self.method,
             mode="matfree",
             num_blocks=self.num_blocks,
             num_epochs=num_epochs,
             history=hist,
-            wall_seconds=wall,
+            wall_seconds=run_span.seconds,
             gamma=gamma,
             eta=eta,
             num_rhs=b.shape[1] if batched else 1,
+            epochs_run=epochs_run,
         )
 
     def open_session(self, **kwargs):

@@ -34,6 +34,7 @@ from repro.core.partition import (
     block_rhs,
     partition_matrix,
 )
+from repro.obs.trace import span
 from repro.sparse.matrix import COOMatrix
 
 _HIGHEST = jax.lax.Precision.HIGHEST  # full f32 (see repro.core.projections)
@@ -215,6 +216,10 @@ class SolveResult:
     gamma: float | None = None
     eta: float | None = None
     num_rhs: int = 1
+    # epochs in which the device ran the epoch body: ``num_epochs`` on the
+    # dense path (its scan never skips), the live epochs of a matfree
+    # ``tol`` solve (frozen epochs skip the body)
+    epochs_run: int | None = None
 
     def _last(self, h):
         v = np.asarray(h[-1])
@@ -521,47 +526,64 @@ class PreparedSolver:
         per_block = self._resolve_dynamics(dynamics)
         b = np.asarray(b)
         batched = b.ndim == 2
-        bvecs = block_rhs(self.mixer, b, np.dtype(self.blocks.dtype))
-        ref = None if x_ref is None else jnp.asarray(x_ref, self.blocks.dtype)
         if x0 is not None and self.method not in ("apc", "dapc"):
             raise ValueError(
                 f"x0 warm start needs a consensus method (apc/dapc); "
                 f"this solver runs {self.method!r}"
             )
-
-        t0 = time.perf_counter()
-        if self.method in ("apc", "dapc"):
-            xbar0 = kwargs.pop("xbar0", None)
-            run = self._consensus_program(num_epochs, kwargs)
-            gamma_op, eta_op = self._dynamics_operands(gamma, eta, per_block)
-            x, hist = run(
-                self.blocks, self.factors, self.projector[1], bvecs,
-                gamma_op, eta_op, ref, xbar0,
-                _as_warm_operand(x0, self.blocks.dtype),
-            )
-        elif self.method == "cgnr":
-            part = Partition(self.blocks, bvecs, self.mode)
-            x, hist = cg.solve_cgnr(part, num_epochs=num_epochs, x_ref=ref, **kwargs)
-        else:  # dgd
-            part = Partition(self.blocks, bvecs, self.mode)
-            kwargs.setdefault("lr", self.factors[0])
-            x, hist = dgd.solve_dgd(part, num_epochs=num_epochs, x_ref=ref, **kwargs)
-        x = jax.block_until_ready(x)
-        wall = time.perf_counter() - t0
+        with span(
+            "solve", path=self.path, k=b.shape[1] if batched else 1,
+            num_epochs=num_epochs,
+        ) as solve_span:
+            with span("solve.rhs"):
+                bvecs = block_rhs(self.mixer, b, np.dtype(self.blocks.dtype))
+                ref = (
+                    None if x_ref is None
+                    else jnp.asarray(x_ref, self.blocks.dtype)
+                )
+            with span("solve.run") as run_span:
+                if self.method in ("apc", "dapc"):
+                    xbar0 = kwargs.pop("xbar0", None)
+                    run = self._consensus_program(num_epochs, kwargs)
+                    gamma_op, eta_op = self._dynamics_operands(
+                        gamma, eta, per_block
+                    )
+                    x, hist = run(
+                        self.blocks, self.factors, self.projector[1], bvecs,
+                        gamma_op, eta_op, ref, xbar0,
+                        _as_warm_operand(x0, self.blocks.dtype),
+                    )
+                elif self.method == "cgnr":
+                    part = Partition(self.blocks, bvecs, self.mode)
+                    x, hist = cg.solve_cgnr(
+                        part, num_epochs=num_epochs, x_ref=ref, **kwargs
+                    )
+                else:  # dgd
+                    part = Partition(self.blocks, bvecs, self.mode)
+                    kwargs.setdefault("lr", self.factors[0])
+                    x, hist = dgd.solve_dgd(
+                        part, num_epochs=num_epochs, x_ref=ref, **kwargs
+                    )
+                x = jax.block_until_ready(x)
+            with span("solve.fetch"):
+                x = np.asarray(x)
+                hist = jax.tree.map(np.asarray, hist)
+            # every method's scan runs its whole budget
+            solve_span.set(epochs_run=num_epochs)
         self.num_solves += 1
 
-        hist = jax.tree.map(np.asarray, hist)
         return SolveResult(
-            x=np.asarray(x),
+            x=x,
             method=self.method,
             mode=self.mode,
             num_blocks=self.num_blocks,
             num_epochs=num_epochs,
             history=hist,
-            wall_seconds=wall,
+            wall_seconds=run_span.seconds,
             gamma=gamma if self.method in ("apc", "dapc") else None,
             eta=eta if self.method in ("apc", "dapc") else None,
             num_rhs=b.shape[1] if batched else 1,
+            epochs_run=num_epochs,
         )
 
     def open_session(self, **kwargs):

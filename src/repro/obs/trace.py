@@ -1,12 +1,27 @@
-"""Span-based request tracing with JSON-lines and Chrome trace-event export.
+"""Host spans on the profiler's clock, and span-based request tracing with
+JSON-lines and Chrome trace-event export.
 
-Answers "where did this request's 40 ms go": every request through the
-serving stack gets a trace id, and each stage it crosses — submit/queue
-wait, batch dispatch, the coalesced solve, checkpoint restores, session
-updates — records one host-side span ``(name, trace_id, t0, t1, args)``.
-Spans are HOST-side only: jitted code is never touched per-epoch, so an
-enabled tracer costs a few dict appends per request, and a disabled one
-costs nothing at all (callers hold ``tracer=None`` and skip the calls).
+Two consumers, one span API:
+
+  * The profiler. ``span(name, **args)`` opens a
+    ``jax.profiler.TraceAnnotation`` named ``"repro." + name`` whose
+    keyword arguments (and whatever ``Span.set`` adds mid-span) become
+    event stats. Under ``jax.profiler.start_trace`` the span lands on the
+    host plane of the ``.xplane.pb`` beside the device ops, on the same
+    clock; with the profiler off it is one cheap call. The solvers
+    (``repro.solve`` and its ``.rhs``/``.run``/``.fetch`` phases) and the
+    serving dispatcher (``repro.server.assemble``/``.solve``/``.deliver``)
+    open such spans on every call.
+  * A ``Tracer``. Answers "where did this request's 40 ms go": every
+    request through the serving stack gets a trace id, and each stage it
+    crosses — submit/queue wait, batch dispatch, the coalesced solve,
+    checkpoint restores, session updates — records one host-side span
+    ``(name, trace_id, t0, t1, args)`` in memory. ``Tracer.begin`` and
+    ``Tracer.span`` open the same profiler annotation as ``span``;
+    ``Tracer.span_at`` back-fills an interval after the fact and reaches
+    the tracer's record only. Jitted code is never touched per-epoch, so
+    an enabled tracer costs a few dict appends per request, and the
+    serving stack skips its calls when it holds ``tracer=None``.
 
 Exports:
 
@@ -19,9 +34,10 @@ Exports:
     so a serving run's queue→dispatch→solve waterfall is visible without
     any post-processing.
 
-Timestamps come from the injectable ``repro.obs.clock`` (monotonic); the
-Chrome export rebases them to the earliest span so Perfetto's clock starts
-near zero.
+A tracer's timestamps come from the injectable ``repro.obs.clock``
+(monotonic); the Chrome export rebases them to the earliest span so
+Perfetto's clock starts near zero. Profiler events take the profiler's own
+timestamps.
 """
 from __future__ import annotations
 
@@ -31,44 +47,87 @@ import threading
 from contextlib import contextmanager
 from typing import Any
 
+from jax.profiler import TraceAnnotation
+
 from repro.obs import clock as obs_clock
 
 SERVER_TRACK = 0  # tid for spans not owned by one request (batches, pool IO)
+PREFIX = "repro."  # profiler event name = PREFIX + span name
 
 
 class Span:
-    """One in-flight span; ``end()`` seals it into the tracer's buffer.
+    """One span; ``end()`` closes its profiler annotation and seals it into
+    its tracer's buffer (when it has a tracer).
 
     ``trace_id`` groups spans of one logical request; ``args`` carry
     structured attributes (batch size, fingerprint, flush reason, ...).
+    ``t0``/``t1`` are readings of ``clock`` (the tracer's, else
+    ``repro.obs.clock.DEFAULT``).
     """
 
-    __slots__ = ("tracer", "name", "cat", "trace_id", "t0", "t1", "args")
+    __slots__ = (
+        "tracer", "clock", "name", "cat", "trace_id", "t0", "t1", "args",
+        "_annotation",
+    )
 
-    def __init__(self, tracer, name, cat, trace_id, t0, args):
+    def __init__(self, tracer, clock, name, cat, trace_id, t0, args,
+                 annotation=None):
         self.tracer = tracer
+        self.clock = clock
         self.name = name
         self.cat = cat
         self.trace_id = trace_id
         self.t0 = t0
         self.t1 = None
         self.args = args
+        self._annotation = annotation
 
     def set(self, **args) -> "Span":
-        """Attach attributes discovered mid-span (e.g. batch size)."""
+        """Attach attributes discovered mid-span (e.g. batch size); they
+        reach the profiler event's stats too."""
         self.args.update(args)
+        if self._annotation is not None and args:
+            self._annotation.set_metadata(**args)
         return self
 
     def end(self, **args) -> "Span":
         if self.t1 is None:  # idempotent: double-end keeps the first seal
-            self.args.update(args)
-            self.t1 = self.tracer._clock.now()
-            self.tracer._seal(self)
+            self.set(**args)
+            self.t1 = self.clock.now()
+            if self._annotation is not None:
+                self._annotation.__exit__(None, None, None)
+            if self.tracer is not None:
+                self.tracer._seal(self)
         return self
 
     @property
+    def seconds(self) -> float:
+        return 0.0 if self.t1 is None else self.t1 - self.t0
+
+    @property
     def duration_ms(self) -> float:
-        return 0.0 if self.t1 is None else (self.t1 - self.t0) * 1e3
+        return self.seconds * 1e3
+
+
+def _open(tracer, clock, name, cat, trace_id, args) -> Span:
+    """Start a live span: its profiler annotation, then its clock reading."""
+    annotation = TraceAnnotation(PREFIX + name, **args)
+    annotation.__enter__()
+    return Span(tracer, clock, name, cat, trace_id, clock.now(), args,
+                annotation)
+
+
+@contextmanager
+def span(name: str, **args: Any):
+    """A program span with no tracer: the profiler event
+    ``"repro." + name`` carrying ``args`` as stats. Yields the ``Span``;
+    its ``set(**args)`` adds stats before the span closes, and its
+    ``seconds`` (default clock) read after the block."""
+    s = _open(None, obs_clock.DEFAULT, name, "program", SERVER_TRACK, args)
+    try:
+        yield s
+    finally:
+        s.end()
 
 
 class Tracer:
@@ -89,8 +148,9 @@ class Tracer:
         self, name: str, trace_id: int = SERVER_TRACK,
         cat: str = "serving", **args: Any,
     ) -> Span:
-        """Open a span at now(); seal it with ``span.end()``."""
-        return Span(self, name, cat, trace_id, self._clock.now(), args)
+        """Open a span at now() — a profiler annotation like ``span``'s,
+        plus this tracer's record; seal it with ``span.end()``."""
+        return _open(self, self._clock, name, cat, trace_id, args)
 
     def span_at(
         self, name: str, t0: float, t1: float,
@@ -98,8 +158,10 @@ class Tracer:
     ) -> Span:
         """Record an already-measured interval (both endpoints known) —
         how the dispatcher back-fills each request's queue span at
-        dispatch time without touching the submit hot path."""
-        span = Span(self, name, cat, trace_id, t0, args)
+        dispatch time without touching the submit hot path. This tracer's
+        record only: a profiler event cannot be back-filled, so the
+        interval never reaches a ``jax.profiler`` trace."""
+        span = Span(self, self._clock, name, cat, trace_id, t0, args)
         span.t1 = t1
         self._seal(span)
         return span

@@ -50,13 +50,19 @@ monotonic clock (``repro.obs.clock``; pass a ``ManualClock`` for
 deterministic timing tests). Pass ``tracer=`` to record per-request spans
 (queue wait, coalesced solve, batch dispatch, pool prepare/restore) with
 zero overhead when left ``None`` — spans are back-filled at dispatch time,
-never touched on the submit hot path.
+never touched on the submit hot path. Whatever the tracer, every coalesced
+solve opens three program spans for ``jax.profiler`` (``repro.obs.trace.
+span``): ``repro.server.assemble`` (stack, bucket padding, warm operand),
+``repro.server.solve`` (the worker thread's pool get, faults and solve)
+and ``repro.server.deliver`` (per-column scatter, watchdog, futures), each
+carrying ``batch`` (a per-server sequence number) and ``batch_size``.
 """
 from __future__ import annotations
 
 import asyncio
 import dataclasses
 import hashlib
+import itertools
 import threading
 from collections import OrderedDict, deque
 from concurrent.futures import ThreadPoolExecutor
@@ -70,7 +76,7 @@ from repro.core.prepared import ColumnResult, PreparedSolver
 from repro.core.session import SESSION_METHODS, DriftPredictor
 from repro.obs import clock as obs_clock
 from repro.obs.metrics import MetricsRegistry
-from repro.obs.trace import SERVER_TRACK, Tracer
+from repro.obs.trace import SERVER_TRACK, Tracer, span
 from repro.serving.checkpoint import CheckpointStore
 from repro.serving.faults import (
     FaultInjector,  # noqa: F401  (re-exported: the server's faults= hook)
@@ -676,6 +682,7 @@ class SolveServer:
         self._dispatchers: dict[str, asyncio.Task] = {}
         self._solve_s: dict[str, float] = {}  # EWMA batch solve time
         self._seq = 0  # submit-order request counter (fault-plan targets)
+        self._batch_seq = itertools.count(1)  # program spans' ``batch``
         # per-fingerprint circuit breaker: consecutive NORMAL-dispatch
         # failures trip it open; recovery-ladder attempts never count
         # (they are already contained)
@@ -1068,9 +1075,7 @@ class SolveServer:
                 )
             return
         try:
-            result, columns, tol, t0, t1 = await self._attempt(
-                fingerprint, live
-            )
+            result, tol, t0, t1, seq = await self._attempt(fingerprint, live)
         except Exception as exc:
             self._c_failures.labels(reason=self._failure_reason(exc)).inc()
             self._breaker_record(fingerprint, ok=False)
@@ -1097,12 +1102,13 @@ class SolveServer:
                     fingerprint, half, "bisect", priority
                 )
             return
-        sick = self._sick_columns(result, len(live), tol)
-        self._breaker_record(fingerprint, ok=True)
-        self._deliver(
-            fingerprint, live, columns, tol, t0, t1, reason, priority,
-            skip=frozenset(sick),
-        )
+        with span("server.deliver", batch=seq, batch_size=len(live)):
+            sick = self._sick_columns(result, len(live), tol)
+            self._breaker_record(fingerprint, ok=True)
+            self._deliver(
+                fingerprint, live, result, tol, t0, t1, reason, priority,
+                skip=frozenset(sick),
+            )
         for i, status in sick.items():
             self._c_failures.labels(reason=status).inc()
             await self._recover(
@@ -1116,75 +1122,82 @@ class SolveServer:
         prep_source: str = "pool",
     ):
         """ONE coalesced solve on the worker thread. Returns ``(result,
-        columns, tol, t_dispatch, t_done)``; raises on any failure
-        (including injected ones). ``prep_source`` picks the ladder rung:
-        ``"pool"`` (normal get), ``"fallback"`` (degraded re-prepare), or
+        tol, t_dispatch, t_done, seq)``, ``seq`` being the batch number
+        its program spans carry; raises on any failure (including
+        injected ones). ``prep_source`` picks the ladder rung: ``"pool"``
+        (normal get), ``"fallback"`` (degraded re-prepare), or
         ``"refresh"`` (checkpoint-bypassing fresh prepare)."""
         loop = asyncio.get_running_loop()
         t_dispatch = self.clock.now()
+        tags = dict(batch=next(self._batch_seq), batch_size=len(batch))
         # the batch shares one batch key (``_PendingQueue.take`` groups on
         # it), so per-request solve options are batch-uniform here
         tol = batch[0].options.tol
         tol = self.tol if tol is None else tol
-        B = np.stack([p.b for p in batch], axis=1)  # (m, k), arrival order
-        if self.bucket_pad and B.shape[1] < self.max_batch:
-            pad = np.zeros((B.shape[0], self.max_batch - B.shape[1]), B.dtype)
-            B = np.concatenate([B, pad], axis=1)
-        # session columns carry a warm start; the masked (x0, mask) operand
-        # lets them batch alongside cold one-shot columns in ONE compiled
-        # program (masked-off columns reduce exactly to the plain init)
-        x0_arg = None
-        if any(p.options.x0 is not None for p in batch):
-            n = next(
-                p.options.x0 for p in batch if p.options.x0 is not None
-            ).shape[0]
-            k = B.shape[1]  # after bucket padding; padded columns stay cold
-            warm = np.zeros((n, k), B.dtype)
-            mask = np.zeros((k,), bool)
-            for i, p in enumerate(batch):
-                if p.options.x0 is not None:
-                    warm[:, i] = p.options.x0
-                    mask[i] = True
-            x0_arg = (warm, mask)
+        with span("server.assemble", **tags):
+            B = np.stack([p.b for p in batch], axis=1)  # (m, k), arrivals
+            if self.bucket_pad and B.shape[1] < self.max_batch:
+                pad = np.zeros(
+                    (B.shape[0], self.max_batch - B.shape[1]), B.dtype
+                )
+                B = np.concatenate([B, pad], axis=1)
+            # session columns carry a warm start; the masked (x0, mask)
+            # operand lets them batch alongside cold one-shot columns in
+            # ONE compiled program (masked-off columns reduce exactly to
+            # the plain init)
+            x0_arg = None
+            if any(p.options.x0 is not None for p in batch):
+                n = next(
+                    p.options.x0 for p in batch if p.options.x0 is not None
+                ).shape[0]
+                k = B.shape[1]  # after bucket padding; padding stays cold
+                warm = np.zeros((n, k), B.dtype)
+                mask = np.zeros((k,), bool)
+                for i, p in enumerate(batch):
+                    if p.options.x0 is not None:
+                        warm[:, i] = p.options.x0
+                        mask[i] = True
+                x0_arg = (warm, mask)
         seqs = tuple(p.seq for p in batch)
 
         def run():
-            # pool access inside the solver thread: a cache miss (or a
-            # ladder re-prepare) factorizes there, and the local reference
-            # keeps the factors alive even if the pool evicts mid-solve
-            if prep_source == "fallback":
-                prep = self.pool.fallback(fingerprint)
-            elif prep_source == "refresh":
-                prep = self.pool.refresh(fingerprint)
-            else:
-                prep = self.pool.get(fingerprint)
-            actions = {}
-            if self.faults is not None:
-                actions = self.faults.on_solve(
-                    fingerprint, seqs, path=getattr(prep, "path", None)
-                )
-            kwargs = dict(self.solve_kwargs)
-            if tol is not None and prep.method in SESSION_METHODS:
-                # arm the masked in-scan early exit at the reporting
-                # tolerance: converged (and zero-padded bucket) columns
-                # freeze instead of burning projector work to the epoch cap
-                kwargs.setdefault("tol", tol)
-            if x0_arg is not None and prep.method in SESSION_METHODS:
-                # the projection warm start is consensus-only; on other
-                # methods the prediction is silently dropped (cold solve)
-                kwargs["x0"] = x0_arg
-            if kwargs.get("block_history") and prep.method not in SESSION_METHODS:
-                # per-block diagnostics are consensus-only (cgnr/dgd have no
-                # block decomposition to attribute residuals to)
-                kwargs.pop("block_history")
-            result = prep.solve(B, num_epochs=self.num_epochs, **kwargs)
-            if actions and self.faults is not None:
-                cols = {s: i for i, s in enumerate(seqs)}
-                result = self.faults.corrupt_result(
-                    result, actions,
-                    {s: cols[s] for s in actions if s in cols},
-                )
-            return result
+            with span("server.solve", **tags):
+                # pool access inside the solver thread: a cache miss (or a
+                # ladder re-prepare) factorizes there, and the local reference
+                # keeps the factors alive even if the pool evicts mid-solve
+                if prep_source == "fallback":
+                    prep = self.pool.fallback(fingerprint)
+                elif prep_source == "refresh":
+                    prep = self.pool.refresh(fingerprint)
+                else:
+                    prep = self.pool.get(fingerprint)
+                actions = {}
+                if self.faults is not None:
+                    actions = self.faults.on_solve(
+                        fingerprint, seqs, path=getattr(prep, "path", None)
+                    )
+                kwargs = dict(self.solve_kwargs)
+                if tol is not None and prep.method in SESSION_METHODS:
+                    # arm the masked in-scan early exit at the reporting
+                    # tolerance: converged (and zero-padded bucket) columns
+                    # freeze instead of burning projector work to the epoch cap
+                    kwargs.setdefault("tol", tol)
+                if x0_arg is not None and prep.method in SESSION_METHODS:
+                    # the projection warm start is consensus-only; on other
+                    # methods the prediction is silently dropped (cold solve)
+                    kwargs["x0"] = x0_arg
+                if kwargs.get("block_history") and prep.method not in SESSION_METHODS:
+                    # per-block diagnostics are consensus-only (cgnr/dgd have no
+                    # block decomposition to attribute residuals to)
+                    kwargs.pop("block_history")
+                result = prep.solve(B, num_epochs=self.num_epochs, **kwargs)
+                if actions and self.faults is not None:
+                    cols = {s: i for i, s in enumerate(seqs)}
+                    result = self.faults.corrupt_result(
+                        result, actions,
+                        {s: cols[s] for s in actions if s in cols},
+                    )
+                return result
 
         result = await loop.run_in_executor(self._executor, run)
         t_done = self.clock.now()
@@ -1198,14 +1211,13 @@ class SolveServer:
             self._g_imbalance.set(
                 float(final.max() / max(float(final.min()), 1e-30))
             )
-        columns = result.per_column(tol=tol)
-        return result, columns, tol, t_dispatch, t_done
+        return result, tol, t_dispatch, t_done, tags["batch"]
 
     def _deliver(
         self,
         fingerprint: str,
         batch: list[_Pending],
-        columns,
+        result,
         tol,
         t_dispatch: float,
         t_done: float,
@@ -1214,9 +1226,10 @@ class SolveServer:
         attempts: int = 1,
         skip: frozenset = frozenset(),
     ) -> None:
-        """Scatter per-column results to the batch's futures (skipping the
-        watchdog-flagged indices in ``skip`` — those recover separately)
-        and record the batch's metrics/spans."""
+        """Scatter the result's columns to the batch's futures (skipping
+        the watchdog-flagged indices in ``skip`` — those recover
+        separately) and record the batch's metrics/spans."""
+        columns = result.per_column(tol=tol)
         solve_ms = (t_done - t_dispatch) * 1e3
         # EWMA batch solve time — what the policy's deadline pull-forward
         # assumes the NEXT batch will cost
@@ -1319,7 +1332,7 @@ class SolveServer:
             t_stage = self.clock.now()
             prep_source = "pool" if stage == "retry" else stage
             try:
-                result, columns, tol, t0, t1 = await self._attempt(
+                result, tol, t0, t1, seq = await self._attempt(
                     fingerprint, [pending], prep_source=prep_source
                 )
             except Exception as exc:
@@ -1332,29 +1345,30 @@ class SolveServer:
                         fingerprint=fingerprint, error=repr(exc),
                     )
                 continue
-            sick = self._sick_columns(result, 1, tol)
-            if sick:
-                last_reason, last_exc = sick[0], None
-                self._c_failures.labels(reason=last_reason).inc()
-                if self.tracer is not None:
-                    self.tracer.span_at(
-                        f"recover.{stage}", t_stage, self.clock.now(),
-                        trace_id=pending.trace_id, cat="fault",
-                        fingerprint=fingerprint, status=last_reason,
+            with span("server.deliver", batch=seq, batch_size=1):
+                sick = self._sick_columns(result, 1, tol)
+                if not sick:
+                    self._c_recovered.inc()
+                    if self.tracer is not None:
+                        self.tracer.span_at(
+                            f"recover.{stage}", t_stage, self.clock.now(),
+                            trace_id=pending.trace_id, cat="fault",
+                            fingerprint=fingerprint, recovered=True,
+                        )
+                    self._deliver(
+                        fingerprint, [pending], result, tol, t0, t1,
+                        f"recover_{stage}", priority, attempts=attempts,
                     )
-                continue
-            self._c_recovered.inc()
+            if not sick:
+                return
+            last_reason, last_exc = sick[0], None
+            self._c_failures.labels(reason=last_reason).inc()
             if self.tracer is not None:
                 self.tracer.span_at(
                     f"recover.{stage}", t_stage, self.clock.now(),
                     trace_id=pending.trace_id, cat="fault",
-                    fingerprint=fingerprint, recovered=True,
+                    fingerprint=fingerprint, status=last_reason,
                 )
-            self._deliver(
-                fingerprint, [pending], columns, tol, t0, t1,
-                f"recover_{stage}", priority, attempts=attempts,
-            )
-            return
         self._fail_request(
             fingerprint, pending, last_reason, attempts, last_exc
         )

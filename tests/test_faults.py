@@ -569,6 +569,9 @@ def test_concurrent_writers_never_expose_a_torn_checkpoint(
             if not store.save("fp", prepared, dict(PREP_KW)):
                 errors.append("save failed")
 
+    # a checkpoint exists before the race, so every read below is a read
+    # during the race, not a read that came before the first write
+    assert store.save("fp", prepared, dict(PREP_KW))
     threads = [threading.Thread(target=writer) for _ in range(4)]
     for t in threads:
         t.start()

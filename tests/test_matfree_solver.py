@@ -248,3 +248,67 @@ def test_serving_queue_with_matfree_system(problem, rhs_batch):
     want = mf.solve(B[:, :3], num_epochs=150).x
     for i, r in enumerate(results):
         np.testing.assert_allclose(r.x, want[:, i], atol=1e-5)
+
+
+# ---------------------------------------------------------------------------
+# program spans and the epochs_run counter
+# ---------------------------------------------------------------------------
+
+
+@pytest.mark.parametrize("gram_solver", ["direct", "pcg"])
+def test_epochs_run_counts_live_epochs(problem, gram_solver):
+    """With ``tol`` the epoch body is skipped once every column froze, so
+    ``epochs_run`` is the slowest column's epochs to tolerance (plus at
+    most one); without it every epoch runs."""
+    B = _straggler_batch(problem, scale=1.0)
+    tol = 1.0
+    prep = prepare(
+        problem.coo, mode="matfree", num_blocks=8, gamma=2.0, eta=1.9,
+        gram_solver=gram_solver,
+    )
+    res = prep.solve(B, num_epochs=300, tol=tol)
+    slowest = int(res.iterations_to_tol(tol).max())
+    assert slowest < 300
+    assert slowest <= res.epochs_run <= slowest + 1
+    if gram_solver == "direct":
+        # the frozen branch writes zero inner depths, the live one ones
+        live = (np.asarray(res.history["inner_iters"]) != 0).any(axis=1)
+        assert res.epochs_run == int(live.sum())
+    assert prep.solve(B, num_epochs=60).epochs_run == 60
+
+
+def test_epochs_run_single_rhs(problem):
+    """An unbatched solve counts the same epochs as its one-column batch."""
+    B = _straggler_batch(problem, scale=1.0)
+    prep = prepare(problem.coo, mode="matfree", num_blocks=8, gamma=2.0, eta=1.9)
+    one = prep.solve(B[:, 5], num_epochs=300, tol=1.0)
+    batch = prep.solve(B[:, 5:6], num_epochs=300, tol=1.0)
+    assert one.epochs_run == batch.epochs_run < 300
+
+
+def test_matfree_solve_spans_under_profiler(problem, profile):
+    """``repro.solve`` on the matfree path carries the live epoch count
+    the result reports; x and the history are bit-identical to a solve
+    with the profiler off."""
+    B = _straggler_batch(problem, scale=1.0)
+    prep = prepare(problem.coo, mode="matfree", num_blocks=8, gamma=2.0, eta=1.9)
+    plain = prep.solve(B, num_epochs=300, tol=1.0)
+    with profile() as events:
+        traced = prep.solve(B, num_epochs=300, tol=1.0)
+    np.testing.assert_array_equal(traced.x, plain.x)
+    for key in ("residual_sq", "inner_iters"):
+        np.testing.assert_array_equal(traced.history[key], plain.history[key])
+    assert traced.epochs_run == plain.epochs_run
+
+    assert [e[0] for e in events] == [
+        "repro.solve", "repro.solve.rhs", "repro.solve.run",
+        "repro.solve.fetch",
+    ]
+    assert events[0][3] == {
+        "path": "matfree", "k": B.shape[1], "num_epochs": 300,
+        "epochs_run": traced.epochs_run,
+    }
+    run = events[2]
+    assert traced.wall_seconds == pytest.approx(
+        (run[2] - run[1]) * 1e-9, abs=2e-3
+    )

@@ -30,7 +30,7 @@ from repro.obs.convergence import (
     per_block_rates,
 )
 from repro.obs.metrics import MetricsRegistry, start_exposition
-from repro.obs.trace import SERVER_TRACK, Tracer, load_trace
+from repro.obs.trace import SERVER_TRACK, Tracer, load_trace, span
 from repro.sparse import make_problem
 
 EPOCHS = 40
@@ -548,3 +548,95 @@ def test_compare_records_fails_on_missing_gated_row(capsys):
     out = capsys.readouterr().out
     assert "kernels/demo" in out  # ungated missing row is noted, not failed
     assert "kernels/other" in out  # fresh-only row noted as ungated
+
+
+# ---------------------------------------------------------------------------
+# program spans on the profiler's clock
+# ---------------------------------------------------------------------------
+
+
+def test_program_span_lands_in_profiler_trace(profile):
+    """``span`` is a profiler event named ``repro.<name>`` whose keyword
+    arguments and mid-span ``set`` stats reach the ``.xplane.pb``; a child
+    opened inside it nests inside it on the same thread."""
+    with profile() as events:
+        with span("probe", path="dense", k=4) as outer:
+            with span("probe.child", batch=7):
+                pass
+            outer.set(epochs_run=600)
+    assert [e[0] for e in events] == ["repro.probe", "repro.probe.child"]
+    (_, t0, t1, stats, line), (_, c0, c1, child_stats, child_line) = events
+    assert stats == {"path": "dense", "k": 4, "epochs_run": 600}
+    assert child_stats == {"batch": 7}
+    assert line == child_line and t0 <= c0 <= c1 <= t1
+    assert outer.args == stats and outer.seconds > 0
+
+
+def test_program_span_without_profiler_keeps_its_record():
+    """With the profiler off a span still times itself and keeps its args;
+    nothing else is recorded anywhere."""
+    with span("probe", k=1) as s:
+        s.set(epochs_run=3)
+    assert s.args == {"k": 1, "epochs_run": 3}
+    assert s.t1 is not None and s.seconds >= 0 and s.tracer is None
+
+
+def test_tracer_spans_share_the_profiler_path(profile):
+    """``Tracer.span``/``Tracer.begin`` open the same profiler event as
+    ``span`` besides the tracer's record; ``span_at`` (back-filled) reaches
+    the tracer only."""
+    tracer = Tracer(clock=ManualClock())
+    with profile() as events:
+        with tracer.span("work", cat="test", batch=1):
+            pass
+        tracer.begin("queue", trace_id=3, priority="bulk").end(batch=2)
+        tracer.span_at("batch", 0.0, 1.0, size=3)
+    assert [(e[0], e[3]) for e in events] == [
+        ("repro.work", {"batch": 1}),
+        ("repro.queue", {"priority": "bulk", "batch": 2}),
+    ]
+    assert [s.name for s in tracer.spans()] == ["work", "queue", "batch"]
+
+
+def test_server_phase_spans_share_batch_numbers(problem, rhs_batch, profile):
+    """Every coalesced solve leaves ``repro.server.assemble``, ``.solve``
+    and ``.deliver`` under one ``batch`` number, in that order, with the
+    solver's own ``repro.solve`` (and its run phase) inside ``.solve``."""
+    B, _ = rhs_batch
+
+    async def main():
+        from repro.serving.queue import SolveServer, replay_trace
+
+        async with SolveServer(
+            max_batch=2, max_wait_ms=2.0, num_epochs=10,
+            prepare_kwargs=PREP_KW,
+        ) as server:
+            fp = server.register(problem.A)
+            await server.submit(fp, B[:, 0])  # prepare and compile first
+            with profile() as events:
+                await replay_trace(server, fp, B, [0.0] * B.shape[1])
+            return events
+
+    events = _run(main())
+    phases = {}
+    for name, s, e, stats, line in events:
+        if name.startswith("repro.server."):
+            phase = name[len("repro.server."):]
+            phases.setdefault(stats["batch"], {})[phase] = (s, e, stats, line)
+    assert phases
+    sizes = 0
+    for batch, ph in phases.items():
+        assert set(ph) == {"assemble", "solve", "deliver"}
+        assert len({p[2]["batch_size"] for p in ph.values()}) == 1
+        sizes += ph["solve"][2]["batch_size"]
+        assert ph["assemble"][1] <= ph["solve"][0]
+        assert ph["solve"][1] <= ph["deliver"][0]
+        s0, s1, _, solve_line = ph["solve"]
+        inner = [
+            e for e in events
+            if e[0] in ("repro.solve", "repro.solve.run")
+            and s0 <= e[1] <= e[2] <= s1
+        ]
+        assert [e[0] for e in inner] == ["repro.solve", "repro.solve.run"]
+        assert all(e[4] == solve_line for e in inner)
+    assert sizes == B.shape[1]

@@ -201,3 +201,64 @@ def test_prepared_solver_reports_setup_and_solves(problem):
     prep.solve(problem.b, num_epochs=5)
     assert prep.num_solves == 1
     assert prep.num_blocks == 8 and prep.num_cols == 96
+
+
+# ---------------------------------------------------------------------------
+# program spans and the epochs_run counter
+# ---------------------------------------------------------------------------
+
+
+def _tree_equal(a, b):
+    if isinstance(a, dict):
+        assert a.keys() == b.keys()
+        for key in a:
+            _tree_equal(a[key], b[key])
+    else:
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+@pytest.mark.parametrize(
+    "method,kw",
+    [("dapc", {"tol": 1e-2}), ("dapc", {}), ("apc", {"tol": 1e-2}),
+     ("cgnr", {}), ("dgd", {})],
+)
+def test_epochs_run_is_the_whole_budget(problem, rhs_batch, method, kw):
+    """The dense path's scans never skip an epoch, so ``epochs_run`` is
+    ``num_epochs`` even where every column reached ``tol`` long before."""
+    B, _ = rhs_batch
+    prep = prepare(problem.A, num_blocks=8, method=method, materialize_p=False)
+    res = prep.solve(B, num_epochs=120, **kw)
+    assert res.epochs_run == 120
+    if kw:
+        assert res.iterations_to_tol(kw["tol"]).max() < 120
+
+
+def test_solve_spans_under_profiler(problem, rhs_batch, profile):
+    """Under ``jax.profiler`` a solve leaves ``repro.solve`` with its args
+    and the ``epochs_run`` stat added at close, and its three phases nested
+    inside it in order; x and the history are bit-identical to a solve
+    with the profiler off, and ``wall_seconds`` is the run phase's span."""
+    B, _ = rhs_batch
+    prep = prepare(problem.A, num_blocks=8, materialize_p=False)
+    plain = prep.solve(B, num_epochs=50, tol=1e-2)
+    with profile() as events:
+        traced = prep.solve(B, num_epochs=50, tol=1e-2)
+    np.testing.assert_array_equal(traced.x, plain.x)
+    _tree_equal(traced.history, plain.history)
+
+    names = [e[0] for e in events]
+    assert names == [
+        "repro.solve", "repro.solve.rhs", "repro.solve.run",
+        "repro.solve.fetch",
+    ]
+    (_, t0, t1, stats, line), *phases = events
+    assert stats == {
+        "path": "dense", "k": B.shape[1], "num_epochs": 50, "epochs_run": 50,
+    }
+    ends = t0
+    for _, s, e, _, phase_line in phases:
+        assert phase_line == line and ends <= s <= e <= t1
+        ends = e
+    run_s = (phases[1][2] - phases[1][1]) * 1e-9
+    assert 0 < traced.wall_seconds <= (t1 - t0) * 1e-9 + 1e-3
+    assert traced.wall_seconds == pytest.approx(run_s, abs=2e-3)
