@@ -17,6 +17,7 @@ from typing import Callable
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 _HIGHEST = jax.lax.Precision.HIGHEST  # full f32 (see repro.core.projections)
 
@@ -83,6 +84,12 @@ def run_consensus(
     residual carried from the previous epoch (no extra einsum). Requires
     (blocks, bvecs); the frozen column's residual history simply repeats
     its converged value, so ``iterations_to_tol`` reports are unchanged.
+    Once EVERY column is frozen the epoch body short-circuits under a
+    ``lax.cond``: no projector apply and no residual pass, the state
+    passes through, and the history row repeats the carried one (the
+    last computed from this same x̄), so results and history are those of
+    the masked scan. ``live_epochs`` counts the epochs that ran the body.
+    Without ``tol`` no cond is traced.
 
     ``compress="bf16_delta"`` halves the consensus all-reduce payload by
     communicating the DELTA mean(x)−x̄ in bf16 (eq. 7 rewritten as
@@ -146,8 +153,7 @@ def run_consensus(
             jnp.mean(eta) if getattr(eta, "ndim", 0) >= 1 else eta
         )
 
-    def step(carry, t):
-        xs, xbar, resid = carry
+    def step(xs, xbar, resid, t):
         xs_new = xs + gam * apply_fn(xbar[None] - xs)  # eq. (6), parallel j
         do_avg = (t + 1) % avg_every == 0
         if compress == "bf16_delta":
@@ -174,16 +180,59 @@ def run_consensus(
             active = resid > tol * tol  # (k,) batched, scalar otherwise
             xs_new = jnp.where(active, xs_new, xs)
             xbar_new = jnp.where(active, xbar_new, xbar)
-        out = metrics(xbar_new)
-        resid_new = out["residual_sq"] if tol is not None else resid
-        return (xs_new, xbar_new, resid_new), out
+        return xs_new, xbar_new, metrics(xbar_new)
 
-    resid0 = init_metrics.get("residual_sq", jnp.zeros(()))
-    (xs, xbar, _), hist = jax.lax.scan(
-        step, (x0s, xbar0, resid0), jnp.arange(num_epochs)
-    )
+    if tol is None:
+        def epoch(carry, t):
+            xs, xbar, out = step(*carry, None, t)
+            return (xs, xbar), out
+
+        init = (x0s, xbar0)
+    else:
+        # the carry holds the last history row, whose residual arms the
+        # mask; an all-frozen epoch would recompute that row from an
+        # unchanged x̄, so it repeats it instead
+        def live(carry, t):
+            xs, xbar, row = carry
+            xs, xbar, out = step(xs, xbar, row["residual_sq"], t)
+            return (xs, xbar, out), out
+
+        def frozen(carry, t):
+            return carry, carry[2]
+
+        def epoch(carry, t):
+            any_active = jnp.any(carry[2]["residual_sq"] > tol * tol)
+            return jax.lax.cond(any_active, live, frozen, carry, t)
+
+        init = (x0s, xbar0, init_metrics)
+    (_, xbar, *_), hist = jax.lax.scan(epoch, init, jnp.arange(num_epochs))
     hist["initial"] = init_metrics
     return xbar, hist
+
+
+def live_epochs(hist: dict, num_epochs: int, tol: float | None) -> int:
+    """Epochs in which a consensus scan ran its epoch body (``run_consensus``
+    here, ``matfree.consensus_epochs`` on the matrix-free path), counted on
+    the host from the history a solve returns.
+
+    Without ``tol`` every epoch is live. With it, epoch t runs the body iff
+    some column's residual at the epoch's start exceeds ``tol²`` — the
+    program's own freeze predicate, applied to the very residuals it
+    emitted (entry t − 1 of ``residual_sq``, the initial one for t = 0) in
+    their own dtype. The matrix-free frozen branch also writes zero inner
+    depths, but a live PCG epoch whose warm-started inner solves start
+    below their tolerance reports zero depth too, so ``inner_iters`` alone
+    would undercount.
+    """
+    if tol is None:
+        return num_epochs
+    resid = np.asarray(hist["residual_sq"])
+    start = np.concatenate([
+        np.reshape(hist["initial"]["residual_sq"], (1, -1)),
+        np.reshape(resid, (num_epochs, -1))[:-1],
+    ])
+    threshold = start.dtype.type(float(tol) ** 2)
+    return int((start > threshold).any(axis=1).sum())
 
 
 def evaluate_candidates(

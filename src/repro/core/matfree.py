@@ -81,6 +81,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core.consensus import live_epochs
 from repro.core.prepared import SolveOptions, SolveResult
 from repro.core.spectra import (
     dynamics_arrays as _dynamics_arrays,
@@ -440,30 +441,6 @@ def consensus_epochs(
     if ref is not None:
         hist["initial"]["mse"] = mse(xbar0)
     return xbar, hist
-
-
-def live_epochs(hist: dict, num_epochs: int, tol: float | None) -> int:
-    """Epochs in which ``consensus_epochs`` ran its epoch body, counted on
-    the host from the history a solve returns.
-
-    Without ``tol`` every epoch is live. With it, epoch t runs the body iff
-    some column's residual at the epoch's start exceeds ``tol²`` — the
-    program's own freeze predicate, applied to the very residuals it
-    emitted (entry t − 1 of ``residual_sq``, the initial one for t = 0) in
-    their own dtype. The frozen branch also writes zero inner depths, but
-    a live PCG epoch whose warm-started inner solves start below their
-    tolerance reports zero depth too, so ``inner_iters`` alone would
-    undercount.
-    """
-    if tol is None:
-        return num_epochs
-    resid = np.asarray(hist["residual_sq"])
-    start = np.concatenate([
-        np.reshape(hist["initial"]["residual_sq"], (1, -1)),
-        np.reshape(resid, (num_epochs, -1))[:-1],
-    ])
-    threshold = start.dtype.type(float(tol) ** 2)
-    return int((start > threshold).any(axis=1).sum())
 
 
 @dataclasses.dataclass

@@ -216,9 +216,9 @@ class SolveResult:
     gamma: float | None = None
     eta: float | None = None
     num_rhs: int = 1
-    # epochs in which the device ran the epoch body: ``num_epochs`` on the
-    # dense path (its scan never skips), the live epochs of a matfree
-    # ``tol`` solve (frozen epochs skip the body)
+    # epochs in which the device ran the epoch body: the live epochs of an
+    # apc/dapc or matfree ``tol`` solve (all-frozen epochs skip the body),
+    # else ``num_epochs``
     epochs_run: int | None = None
 
     def _last(self, h):
@@ -506,7 +506,8 @@ class PreparedSolver:
         compiled program untouched. For apc/dapc, ``tol`` arms the masked per-column
         early exit: columns that reach ``residual_sq <= tol²`` freeze
         in-scan (``repro.core.consensus``) while the batch keeps one
-        compiled shape — matching the matfree path's ``solve(tol=...)``.
+        compiled shape, and the epoch body is skipped once all have —
+        matching the matfree path's ``solve(tol=...)``.
 
         ``dynamics`` overrides the prepared default per solve:
         ``"per_block"`` runs eqs. (6)-(7) with the spectral per-block
@@ -568,8 +569,13 @@ class PreparedSolver:
             with span("solve.fetch"):
                 x = np.asarray(x)
                 hist = jax.tree.map(np.asarray, hist)
-            # every method's scan runs its whole budget
-            solve_span.set(epochs_run=num_epochs)
+            # a consensus scan skips its body once every column met tol;
+            # cgnr's and dgd's scans run their whole budget
+            epochs_run = (
+                consensus.live_epochs(hist, num_epochs, kwargs.get("tol"))
+                if self.method in ("apc", "dapc") else num_epochs
+            )
+            solve_span.set(epochs_run=epochs_run)
         self.num_solves += 1
 
         return SolveResult(
@@ -583,7 +589,7 @@ class PreparedSolver:
             gamma=gamma if self.method in ("apc", "dapc") else None,
             eta=eta if self.method in ("apc", "dapc") else None,
             num_rhs=b.shape[1] if batched else 1,
-            epochs_run=num_epochs,
+            epochs_run=epochs_run,
         )
 
     def open_session(self, **kwargs):
