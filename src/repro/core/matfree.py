@@ -33,10 +33,17 @@ carrying the probe ``z_j = A_j x̄`` through the ``lax.scan``:
   * ``z`` doubles as the residual metric AND the projection input: the
     paper's iterates keep A_j x_j = b_j invariant (every update moves
     inside the block solution set), so A_j(x̄ − x_j) = z_j − b_j — no
-    second forward product. With the inexact PCG inner solve the invariant
-    drifts, so that path additionally carries ``w_j = A_j x_j``, updated
-    for FREE from the CG residual (x_j ← x_j + γ(v_j − A_jᵀy_j) implies
-    A_j x_j ← w_j + γ·r_cg).
+    second forward product. The PCG inner solve stops at a residual r_cg
+    (at ``inner_tol`` or at the ``inner_iters`` cap), so that path holds
+    the invariant A_j x_j = b_j + γ·r_cg instead, carrying ``w_j = A_j
+    x_j`` for FREE from the CG residual. Each epoch folds the carried
+    defect back into its Gram right-hand side, v_j = z_j − w_j − (b_j −
+    w_j)/γ: the step x_j ← x_j + γ(x̄ − x_j − A_jᵀy_j) then lands on
+    A_j x_j⁺ = b_j + γ·r_cg, so the blocks keep projecting onto
+    {A_j x = b_j} and the inner error shrinks with the step instead of
+    accumulating into a nearby system the consensus would settle on.
+    Elementwise only: no tile pass, no collective. (γ is γ_j under
+    per-block dynamics.)
   * ``z`` is reconstructed each epoch from the identity
     x̄⁺ = KNOWN − (ηγ/J)·Σ_j A_jᵀy_j, where KNOWN depends only on state
     available BEFORE the transpose product. That is what makes the two
@@ -182,6 +189,22 @@ def _pcg_gram(
     return y, used, r
 
 
+def pcg_gram_iters(
+    hist: dict, num_epochs: int, epochs_run: int, warm_start: bool
+) -> int:
+    """Gram SpMVs a PCG-path solve ran, read off its history: the set-up
+    solve's depth plus each epoch's deepest column (a frozen epoch of a
+    ``tol`` solve records zeros, so only live epochs add). A sharded
+    history already holds each column's deepest shard, so each epoch
+    counts its slowest shard: the SpMVs between two all-reduces. A
+    ``warm_start`` solve runs one more in each of its ``epochs_run`` live
+    epochs, the warm residual, which no depth count records."""
+    per_epoch = np.reshape(hist["inner_iters"], (num_epochs, -1)).max(axis=1)
+    setup = np.max(hist["initial"]["inner_iters"])
+    warm = epochs_run if warm_start else 0
+    return int(setup + per_epoch.sum() + warm)
+
+
 def _gram_pinv(op: PartitionedBSR, dtype) -> np.ndarray:
     """Per-block dense pseudo-inverse of the Gram shards, (J, p_pad, p_pad),
     as a host array (the caller places it).
@@ -269,7 +292,11 @@ def consensus_epochs(
     permutation — is strictly block-local, which is what makes the sharded
     epoch's collective payload at most n·k + k. The inner-CG depth counts
     in ``history["inner_iters"]`` are this caller's own blocks'; a sharded
-    caller takes their max over shards after the scan.
+    caller takes their max over shards after the scan. The PCG path's
+    carried ``w_j = A_j x_j`` holds A_j x_j = b_j + γ·r_cg, the last inner
+    residual scaled by the step, and its epoch folds the defect b_j − w_j
+    back into the Gram right-hand side (module docstring) — block-local
+    too, so the correction adds nothing to the collective budget.
 
     To keep that bound at ONE consensus collective, the global block mean
     ``q = mean_j x_j`` is carried through the scan: the end-of-epoch mean
@@ -340,7 +367,8 @@ def consensus_epochs(
     x0s = op.rmatvec(y0, use_kernels)
     if xq is not None:
         x0s = x0s + xq
-    # the CG residual hands back w0 = A_j x_j(0) = G y0 (+ A_j x0) for free
+    # the CG residual hands back w0 = A_j x_j(0) = b_j − r0 for free (an x0
+    # shift included); the first epoch folds the defect r0 back in
     w0 = bvecs - r0
     xbar0 = block_mean(x0s)  # eq. (5)
     z0 = op.matvec(xbar0, use_kernels)  # probe of x̄_0
@@ -351,6 +379,10 @@ def consensus_epochs(
             y = jnp.einsum("jqp,jpk->jqk", gram_inv, u, precision=_HIGHEST)
             used, r = ones, None
         else:
+            # fold the carried defect b_j − w_j back in, so the step below
+            # lands A_j x_j⁺ on b_j + γ·r and not on w_j + γ·r (see the
+            # module docstring)
+            u = u - (bvecs - w) / gam
             y, used, r = _pcg_gram(
                 op, u, diag_inv, inner_iters, inner_tol, use_kernels,
                 warm=ywarm if warm_start else None, active=active,
@@ -378,9 +410,9 @@ def consensus_epochs(
         else:
             xbar_new = eta * q_new + (1.0 - eta) * xbar  # eq. (7)
         z_new = f + op.matvec(xbar_new - known, use_kernels)
-        # exact inner solve keeps the paper's A_j x_j = b_j invariant,
-        # so w stays put; inexact CG drifts it by r
-        w_new = w if direct else w + gam * r
+        # an exact inner solve keeps the paper's A_j x_j = b_j, so w stays
+        # put; an inexact one leaves A_j x_j⁺ = b_j + γ·r
+        w_new = w if direct else bvecs + gam * r
         if active is not None:
             col = active[None]  # (1, k) over (n, k) state
             blk = active[None, None]  # (1, 1, k) over (J, ·, k)
@@ -693,7 +725,14 @@ class MatrixFreePreparedSolver:
                         else a, hist,
                     )
             epochs_run = live_epochs(hist, num_epochs, tol)
-            solve_span.set(epochs_run=epochs_run)
+            # the direct path applies its Gram inverse once per live epoch
+            gram_iters = (
+                epochs_run if self.gram_solver == "direct"
+                else pcg_gram_iters(
+                    hist, num_epochs, epochs_run, self.warm_start
+                )
+            )
+            solve_span.set(epochs_run=epochs_run, gram_iters=gram_iters)
         self.num_solves += 1
 
         return SolveResult(
@@ -708,6 +747,7 @@ class MatrixFreePreparedSolver:
             eta=eta,
             num_rhs=b.shape[1] if batched else 1,
             epochs_run=epochs_run,
+            gram_iters=gram_iters,
         )
 
     def open_session(self, **kwargs):

@@ -25,9 +25,12 @@ heavy linear algebra worker-local the same way):
     applies the per-block pseudo-inverses as a local einsum, ``"pcg"``
     iterates on the local sparse Gram shards with a shard-local stopping
     test (its ``while_loop`` trip count may differ per device — that is
-    why the program runs under ``shard_map_unchecked``). The PCG depth
-    counts of ``history["inner_iters"]`` are reporting too: each shard
-    emits its own, and the max over shards is taken after the scan.
+    why the program runs under ``shard_map_unchecked``). A capped PCG
+    solve's carried defect b_j − A_j x_j is folded back per block
+    (``consensus_epochs``), so it stays shard-local as well. The PCG
+    depth counts of ``history["inner_iters"]`` are reporting too: each
+    shard emits its own, and the max over shards is taken after the
+    scan (``SolveResult.gram_iters`` counts each epoch's slowest shard).
 
 ``prepare(A, mode="matfree", mesh=...)`` builds one of these; the solve
 contract (``SolveResult``, batched RHS, per-column early exit, serving

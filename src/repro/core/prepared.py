@@ -220,6 +220,9 @@ class SolveResult:
     # apc/dapc or matfree ``tol`` solve (all-frozen epochs skip the body),
     # else ``num_epochs``
     epochs_run: int | None = None
+    # matfree only: Gram SpMVs the inner solves ran (PCG: set-up depth plus
+    # each live epoch's deepest column; direct: ``epochs_run``)
+    gram_iters: int | None = None
 
     def _last(self, h):
         v = np.asarray(h[-1])

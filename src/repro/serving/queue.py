@@ -678,6 +678,11 @@ class SolveServer:
             "server_breaker_transitions_total",
             "circuit breaker transitions, by target state",
         )
+        # the solve span's Gram SpMV count, summed over batches
+        self._c_gram_iters = m.counter(
+            "server_gram_iters_total",
+            "Gram SpMVs the matfree inner solves ran",
+        )
         self._queues: dict[str, _PendingQueue] = {}
         self._dispatchers: dict[str, asyncio.Task] = {}
         self._solve_s: dict[str, float] = {}  # EWMA batch solve time
@@ -770,6 +775,7 @@ class SolveServer:
             "server_failures_total", "server_retries_total",
             "server_recovered_requests_total",
             "server_failed_requests_total", "server_cancelled_total",
+            "server_gram_iters_total",
         ):
             metric = self.metrics.get(name)
             if metric is not None:
@@ -1201,6 +1207,8 @@ class SolveServer:
 
         result = await loop.run_in_executor(self._executor, run)
         t_done = self.clock.now()
+        if result.gram_iters is not None:
+            self._c_gram_iters.inc(result.gram_iters)
         trace = result.history.get("block_residual_sq")
         if trace is not None:
             # heterogeneity gauge: how unevenly the blocks finished — the

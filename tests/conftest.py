@@ -68,3 +68,50 @@ def profile(tmp_path):
         events.extend(read_program_events(directory))
 
     return run
+
+
+def hpcg_stencil(nx: int, ny: int, nz: int):
+    """HPCG's matrix (Heroux, Dongarra, Luszczek, "HPCG Technical
+    Specification", SAND2013-8752) on an ``nx × ny × nz`` grid, as a float64
+    scipy CSR: a 27-point stencil, 26 on the diagonal and −1 for each
+    neighbour inside the grid, so it is symmetric positive definite. Point
+    ``(ix, iy, iz)`` is row ``ix + nx * (iy + ny * iz)``."""
+    import numpy as np
+    import scipy.sparse as sp
+
+    index = np.arange(nx * ny * nz).reshape(nz, ny, nx)
+    rows, cols = [], []
+    for dz in (-1, 0, 1):
+        for dy in (-1, 0, 1):
+            for dx in (-1, 0, 1):
+                src = index[max(0, -dz):nz - max(0, dz),
+                            max(0, -dy):ny - max(0, dy),
+                            max(0, -dx):nx - max(0, dx)]
+                rows.append(src.ravel())
+                cols.append((src + dx + nx * (dy + ny * dz)).ravel())
+    rows, cols = np.concatenate(rows), np.concatenate(cols)
+    vals = np.where(rows == cols, 26.0, -1.0)
+    n = nx * ny * nz
+    return sp.csr_matrix((vals, (rows, cols)), shape=(n, n))
+
+
+@pytest.fixture(scope="session")
+def hpcg():
+    """``hpcg(nx, ny, nz, k=4, seed=0)`` -> ``(coo, A, B)``: the stencil as
+    the solver's ``COOMatrix`` and as a float64 CSR, and ``k`` consistent
+    float64 right-hand sides ``B = A X`` (X ~ N(0, 1) from the seed), scaled
+    so that the smallest ‖b‖ is 1, as the benchmark scales them."""
+    import numpy as np
+
+    from repro.sparse import COOMatrix
+
+    def make(nx, ny, nz, k=4, seed=0):
+        A = hpcg_stencil(nx, ny, nz)
+        c = A.tocoo()
+        coo = COOMatrix(
+            c.row.astype(np.int32), c.col.astype(np.int32), c.data, A.shape
+        )
+        B = A @ np.random.default_rng(seed).standard_normal((A.shape[1], k))
+        return coo, A, B / np.linalg.norm(B, axis=0).min()
+
+    return make

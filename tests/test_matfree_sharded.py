@@ -180,6 +180,109 @@ def test_serving_pool_routes_sharded():
         np.testing.assert_allclose(r.x, want[:, i], atol=1e-5)
 
 
+# HPCG's stencil with the inner PCG capped short of an exact Gram solve:
+# the grids and caps of test_matfree_solver.py's HPCG_CAPPED
+HPCG_CAPPED = [((2, 2, 4), 1), ((4, 4, 4), 4)]
+HPCG_KW = dict(mode="matfree", num_blocks=8, gamma=GAMMA, eta=ETA,
+               balance=False, gram_solver="pcg")
+HPCG_TOL, HPCG_EPOCHS = 1e-5, 600
+
+
+@pytest.mark.parametrize("grid,cap", HPCG_CAPPED)
+def test_sharded_capped_pcg_matches_single_host(hpcg, grid, cap):
+    """The sharded epoch folds the same block-local defect back into its
+    Gram solves: capped PCG on the mesh reaches tol at the single-host
+    epochs, with the single-host answer and Gram SpMV count."""
+    coo, _, B = hpcg(*grid)
+    B = B.astype(np.float32)
+    sh = prepare(coo, mesh=_mesh1(), inner_iters=cap, **HPCG_KW)
+    s1 = prepare(coo, inner_iters=cap, **HPCG_KW)
+    r_sh = sh.solve(B, num_epochs=HPCG_EPOCHS, tol=HPCG_TOL)
+    r_s1 = s1.solve(B, num_epochs=HPCG_EPOCHS, tol=HPCG_TOL)
+    its = r_s1.iterations_to_tol(HPCG_TOL)
+    assert (its < HPCG_EPOCHS).all()
+    np.testing.assert_array_equal(r_sh.iterations_to_tol(HPCG_TOL), its)
+    scale = np.abs(r_s1.x).max()
+    assert float(np.abs(r_sh.x - r_s1.x).max() / scale) <= 1e-5
+    assert r_sh.gram_iters == r_s1.gram_iters
+    assert r_sh.epochs_run == r_s1.epochs_run
+
+
+MULTI_DEVICE_HPCG_SCRIPT = textwrap.dedent(
+    """
+    import os, sys
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+    import jax, numpy as np
+    from repro.core import prepare
+    from repro.obs import audit_epoch_collectives
+    from repro.sparse import COOMatrix
+
+    assert jax.device_count() == 4, jax.device_count()
+    mesh = jax.make_mesh((4,), ("data",))
+    tol, epochs = float(sys.argv[2]), int(sys.argv[3])
+    cases = np.load(sys.argv[1])
+    for name in sorted({key.split(".")[0] for key in cases.files}):
+        a = {f: cases[name + "." + f] for f in ("rows", "cols", "vals", "B")}
+        n, k = a["B"].shape
+        coo = COOMatrix(a["rows"], a["cols"], a["vals"], (n, n))
+        kw = dict(mode="matfree", num_blocks=8, gamma=2.0, eta=1.9,
+                  balance=False, gram_solver="pcg",
+                  inner_iters=int(cases[name + ".cap"]))
+        sh = prepare(coo, mesh=mesh, **kw)
+        s1 = prepare(coo, **kw)
+        r_sh = sh.solve(a["B"], num_epochs=epochs, tol=tol)
+        r_s1 = s1.solve(a["B"], num_epochs=epochs, tol=tol)
+        its = r_s1.iterations_to_tol(tol)
+        assert (its < epochs).all(), its
+        # the pmean sums the four shards' means where one device sums all
+        # eight blocks: float32 rounding apart, so a column that meets tol
+        # within that rounding may meet it one epoch apart, its answer one
+        # step (at the scale of tol) apart
+        slip = np.abs(r_sh.iterations_to_tol(tol) - its)
+        assert slip.max() <= 1, slip
+        relerr = float(np.abs(r_sh.x - r_s1.x).max() / np.abs(r_s1.x).max())
+        assert relerr <= 1e-4, relerr
+        # the slowest shard's Gram SpMVs are the single device's, up to
+        # one capped epoch for each epoch of slip
+        more = abs(r_sh.epochs_run - r_s1.epochs_run)
+        assert more <= 1
+        assert abs(r_sh.gram_iters - r_s1.gram_iters) <= kw["inner_iters"] * more
+        # one n·k pmean an epoch, and the k-length psum only under tol
+        audit_epoch_collectives(sh, a["B"], num_epochs=6, max_ops=1,
+                                max_payload_elems=n * k)
+        audit_epoch_collectives(sh, a["B"], num_epochs=6, tol=tol,
+                                max_ops=2, max_payload_elems=n * k + k)
+        print(name, "OK relerr", relerr, "gram_iters", r_sh.gram_iters)
+    """
+)
+
+
+def test_multi_device_capped_pcg_subprocess(hpcg, tmp_path):
+    """On a real 4-device CPU mesh (two blocks a device), capped PCG on
+    HPCG's stencil reaches tol with the single-device answer, epochs and
+    Gram SpMV count, inside the epoch's collective budget."""
+    cases = {}
+    for grid, cap in HPCG_CAPPED:
+        coo, _, B = hpcg(*grid)
+        name = "x".join(map(str, grid))
+        cases.update({
+            f"{name}.rows": coo.rows, f"{name}.cols": coo.cols,
+            f"{name}.vals": coo.vals, f"{name}.B": B.astype(np.float32),
+            f"{name}.cap": np.int64(cap),
+        })
+    np.savez(tmp_path / "cases.npz", **cases)
+    env = dict(os.environ)
+    env["PYTHONPATH"] = "src"
+    out = subprocess.run(
+        [sys.executable, "-c", MULTI_DEVICE_HPCG_SCRIPT,
+         str(tmp_path / "cases.npz"), str(HPCG_TOL), str(HPCG_EPOCHS)],
+        capture_output=True, text=True, env=env,
+        cwd=os.path.dirname(os.path.dirname(__file__)), timeout=600,
+    )
+    assert out.returncode == 0, f"stdout:\n{out.stdout}\nstderr:\n{out.stderr}"
+    assert out.stdout.count(" OK ") == len(HPCG_CAPPED)
+
+
 MULTI_DEVICE_MATFREE_SCRIPT = textwrap.dedent(
     """
     import os
