@@ -13,10 +13,13 @@ amortize it across right-hand sides:
     step, O(n²) per block, and batched over a trailing RHS axis.
 ``setup_decomposed`` composes the two (the original single-shot path).
 
-Two execution profiles:
-  * ``materialize_p=True``  — paper-faithful: dense P_j built per block.
-  * ``materialize_p=False`` — beyond-paper: implicit P v = v − Wᵀ(W v)
-    (two tall-skinny MXU matmuls; O(np) memory; see DESIGN.md §1.2).
+Two projector forms:
+  * materialized — paper-faithful: dense n×n P_j built per block.
+  * implicit — beyond-paper: P v = v − Wᵀ(W v) (two tall-skinny MXU
+    matmuls; O(np) memory; see DESIGN.md §1.2).
+``projector_form`` picks the one that reads fewer bytes an epoch from the
+block shape; ``materialize_p=True``/``False`` pins it (``solve_dapc``, the
+paper-faithful one-shot path, pins the materialized form by default).
 ``use_kernels=True`` routes the triangular solve and the fused consensus
 update through the Pallas TPU kernels (interpret mode on CPU).
 """
@@ -95,6 +98,24 @@ def setup_decomposed(
     Ws, Rs = qr_blocks(blocks, mode)
     x0s = initial_from_factors(Ws, Rs, bvecs, mode, use_kernels)
     return x0s, Ws
+
+
+def projector_form(
+    p: int, n: int, materialize_p: bool | None = None, use_kernels: bool = False
+) -> str:
+    """How an epoch applies P_j: ``"dense"`` (the materialized n×n P_j) or
+    the factored v − Wᵀ(W v), ``"implicit"`` or ``"kernels"`` (Pallas).
+
+    ``materialize_p=None`` takes the form that reads fewer bytes an epoch:
+    the factored form reads the p×n W_j twice, P_j is n×n, so it wins when
+    2p < n (wide blocks; FLOPs are in the same ratio, at every batch
+    width). ``True``/``False`` pin the form.
+    """
+    if materialize_p is None:
+        materialize_p = 2 * p >= n
+    if materialize_p:
+        return "dense"
+    return "kernels" if use_kernels else "implicit"
 
 
 def make_apply(Ws: jnp.ndarray, materialize_p: bool, use_kernels: bool = False):

@@ -15,6 +15,11 @@ form iterates all k systems in one compiled program — the projector
 application becomes (J, p, n) × (J, n, k) einsums feeding the MXU — which is
 how request batching in the serving path gets its throughput
 (benchmarks/multirhs.py measures both effects).
+
+The dapc projector's form is chosen at prepare time from the block shape
+(``dapc.projector_form``): the implicit v − Wᵀ(W v) where it reads fewer
+bytes an epoch than the materialized n×n P_j, else P_j; ``materialize_p``
+pins either. ``PreparedSolver.projector_form`` reports the choice.
 """
 from __future__ import annotations
 
@@ -71,7 +76,7 @@ class PrepareConfig:
     dtype: Any = None
     gamma: float = 1.0
     eta: float = 0.9
-    materialize_p: bool = True
+    materialize_p: bool | None = None  # None: dapc.projector_form by shape
     use_kernels: bool = False
     block_shape: tuple[int, int] | None = None
     inner_iters: int | None = None
@@ -330,7 +335,6 @@ class PreparedSolver:
     method: str
     gamma: float
     eta: float
-    materialize_p: bool
     use_kernels: bool
     factors: tuple  # method-specific cached setup (see prepare())
     projector: tuple  # ("dense"|"implicit"|"kernels", operand array) or ()
@@ -374,6 +378,21 @@ class PreparedSolver:
                 seen.add(id(a))
                 total += int(a.nbytes)
         return total
+
+    @property
+    def projector_form(self) -> str | None:
+        """How an epoch applies P_j: "dense", "implicit", "kernels", or
+        None for the methods with no projector (dgd, cgnr)."""
+        return self.projector[0] if self.projector else None
+
+    @property
+    def projector_bytes(self) -> int:
+        """Bytes the projector reads per live epoch: each dense P_j once, or
+        each factor W_j twice (W v, then Wᵀ u)."""
+        if not self.projector:
+            return 0
+        kind, operand = self.projector
+        return int(operand.nbytes) * (1 if kind == "dense" else 2)
 
     def _resolve_dynamics(self, dynamics: str | None) -> bool:
         """Resolve a solve-time ``dynamics`` override against the prepared
@@ -535,9 +554,14 @@ class PreparedSolver:
                 f"x0 warm start needs a consensus method (apc/dapc); "
                 f"this solver runs {self.method!r}"
             )
+        proj_stats = (
+            {"projector": self.projector_form,
+             "projector_bytes": self.projector_bytes}
+            if self.projector else {}
+        )
         with span(
             "solve", path=self.path, k=b.shape[1] if batched else 1,
-            num_epochs=num_epochs,
+            num_epochs=num_epochs, **proj_stats,
         ) as solve_span:
             with span("solve.rhs"):
                 bvecs = block_rhs(self.mixer, b, np.dtype(self.blocks.dtype))
@@ -655,7 +679,6 @@ class PreparedSolver:
             "mode": self.mode,
             "gamma": float(self.gamma),
             "eta": float(self.eta),
-            "materialize_p": bool(self.materialize_p),
             "use_kernels": bool(self.use_kernels),
             "setup_seconds": float(self.setup_seconds),
             "mixer": mixer_meta,
@@ -709,7 +732,6 @@ class PreparedSolver:
             method=meta["method"],
             gamma=meta["gamma"],
             eta=meta["eta"],
-            materialize_p=meta["materialize_p"],
             use_kernels=meta["use_kernels"],
             factors=factors,
             projector=projector,
@@ -726,7 +748,7 @@ def prepare(
     dtype=None,
     gamma: float = 1.0,
     eta: float = 0.9,
-    materialize_p: bool = True,
+    materialize_p: bool | None = None,
     use_kernels: bool = False,
     block_shape: tuple[int, int] | None = None,
     inner_iters: int | None = None,
@@ -771,7 +793,11 @@ def prepare(
     bit-identical to the historical solver.
 
     Cached per method (dense path):
-      * dapc — (W_j, R_j) reduced-QR factors (paper eqs. 1/4);
+      * dapc — (W_j, R_j) reduced-QR factors (paper eqs. 1/4), and the
+               projector in the form ``dapc.projector_form`` picks:
+               ``materialize_p=None`` (default) takes the materialized P_j
+               or the implicit v − Wᵀ(W v), whichever reads fewer bytes
+               an epoch for the block shape; ``True``/``False`` pin it;
       * apc  — (A_j⁺, P_j) pseudoinverse + dense projector (the classical
                setup the paper's decomposition replaces);
       * dgd  — the 1/λ_max(AᵀA) step size (power iteration);
@@ -841,14 +867,15 @@ def prepare(
     if method == "dapc":
         Ws, Rs = dapc.qr_blocks(blocks, resolved)
         factors = (Ws, Rs)
-        if materialize_p:
+        form = dapc.projector_form(
+            blocks.shape[1], blocks.shape[2], materialize_p, use_kernels
+        )
+        if form == "dense":
             # paper-faithful dense P_j, built ONCE here (not per solve)
             Ps = jax.vmap(projections.materialize)(Ws)
             projector = ("dense", Ps)
-        elif use_kernels:
-            projector = ("kernels", Ws)
         else:
-            projector = ("implicit", Ws)
+            projector = (form, Ws)
     elif method == "apc":
         pinvs, Ps = apc.classical_factors(blocks, resolved)
         factors = (pinvs, Ps)
@@ -873,7 +900,6 @@ def prepare(
         method=method,
         gamma=gamma,
         eta=eta,
-        materialize_p=materialize_p,
         use_kernels=use_kernels,
         factors=factors,
         projector=projector,

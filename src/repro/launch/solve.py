@@ -35,7 +35,8 @@ def main():
                          "devices under JAX_PLATFORMS=cpu (requires --mode "
                          "matfree)")
     ap.add_argument("--implicit-p", action="store_true",
-                    help="beyond-paper: never materialize the projector")
+                    help="pin the implicit projector (default: the form "
+                         "that reads fewer bytes an epoch)")
     ap.add_argument("--kernels", action="store_true",
                     help="route through the Pallas TPU kernels")
     args = ap.parse_args()
@@ -66,7 +67,10 @@ def main():
     prob = make_problem(n=args.n, m=args.m, seed=0, dtype=np.float32)
     kw = {}
     if args.method == "dapc":
-        kw = {"materialize_p": not args.implicit_p, "use_kernels": args.kernels}
+        kw = {
+            "materialize_p": False if args.implicit_p else None,
+            "use_kernels": args.kernels,
+        }
     # square systems stay sparse end to end: hand prepare the COO so the
     # matfree path (picked or forced) never sees a dense copy
     A = prob.coo if prob.shape[0] == prob.shape[1] else prob.A

@@ -256,9 +256,11 @@ def test_solve_spans_under_profiler(problem, rhs_batch, profile):
         "repro.solve.fetch",
     ]
     (_, t0, t1, stats, line), *phases = events
+    Ws = prep.factors[0]
     assert stats == {
         "path": "dense", "k": B.shape[1], "num_epochs": 50,
         "epochs_run": traced.epochs_run,
+        "projector": "implicit", "projector_bytes": 2 * Ws.nbytes,
     }
     assert traced.epochs_run == plain.epochs_run < 50
     ends = t0

@@ -6,16 +6,23 @@ would raise (SMEM/VMEM overruns, unsupported lowerings). Widths are those of
 ``chip_smoke.py``'s kernel phase: the matfree system's blocked-ELL operator
 (n = 16384, J = 8, 8×8 tiles, 224 slots per block-row unbalanced; its Gram
 shards 248 slots over 256 column blocks) at a k = 8 batch, and the dense
-phase's blocks (m = 16384, n = 8192, J = 8 → p = 2048, wide regime).
+phase's blocks (m = 16384, n = 8192, J = 8 → p = 2048, wide regime), whose
+whole consensus solve program is compiled too, with the implicit projector
+``prepare`` picks there.
 
 The topology is described inside a fixture, never at import: only one
 process may load the TPU library, and each test worker imports every test
 file.
 """
+import re
+
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 from jax.sharding import SingleDeviceSharding
+
+from repro.core import prepare
 
 from repro.kernels.project.project import consensus_update_padded
 from repro.kernels.spmm.spmm import spmm_fused_padded, spmm_padded
@@ -107,3 +114,26 @@ def test_trisolve_compiles_at_dense_block_width(one_chip, lower):
         lower=lower,
     )
     assert "tpu_custom_call" in text
+
+
+def test_dense_solve_reads_the_factor_as_stored(one_chip):
+    """The dense consensus solve at the dense phase's width takes the
+    implicit projector, and its program holds no copy or transpose of the
+    (J, p, n) factor W and no n×n projector: W v and Wᵀ u both stream W in
+    its stored layout."""
+    a = np.random.default_rng(0).standard_normal((128, 64)).astype(np.float32)
+    prep = prepare(a, num_blocks=J)  # p = 16 < n / 2: wide, implicit
+    assert prep.mode == "wide" and prep.projector_form == "implicit"
+    run = prep._consensus_program(600, {"tol": 1e-5})
+    factor = _shape(one_chip, (J, P_PAD, N))
+    scalar = _shape(one_chip, ())
+    text = run.lower(
+        factor, (factor, _shape(one_chip, (J, P_PAD, P_PAD))), factor,
+        _shape(one_chip, (J, P_PAD, K)), scalar, scalar, None, None, None,
+    ).compile().as_text()
+    assert " while(" in text
+    moved = re.escape(f"f32[{J},{P_PAD},{N}]")
+    moved += r"\S* (copy|copy-start|transpose)\("
+    assert not re.search(moved, text)
+    assert f"f32[{J},{N},{P_PAD}]" not in text  # no Wᵀ anywhere
+    assert f"f32[{J},{N},{N}]" not in text  # no materialized P_j
