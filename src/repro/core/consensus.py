@@ -1,8 +1,21 @@
-"""The APC consensus iteration (paper eqs. 6–7) as a jitted ``lax.scan``.
+"""The APC consensus iteration (paper eqs. 6–7): the one epoch loop.
 
-Shared by classical APC and decomposed APC — the two differ only in how the
-per-block initial solutions and projectors are produced (Algorithm 1 steps
-2–3), not in the iteration itself (steps 5–8).
+``consensus_scan`` is the only ``lax.scan`` over consensus epochs in the
+tree. Every solver is a caller of it that supplies what is its own — the
+block projection, the per-block residual, its private carried state and
+the cross-shard reductions — and inherits everything else: eq. (6) and
+the eq. (7) average (scalar or per-block γ_j/η_j, ``avg_every``,
+``bf16_delta``, the straggler publish mask), the ``tol`` freeze mask and
+the all-frozen skip, and the history layout.
+
+  * ``run_consensus`` (here): the dense projector P_j or its factored
+    form v − Wᵀ(W v), the residual as an einsum over the row blocks;
+    classical and decomposed APC differ only in how the per-block initial
+    solutions and projectors are produced (Algorithm 1 steps 2–3);
+  * ``repro.core.matfree.consensus_epochs``: the Gram solve with the fused
+    tile pass, single host or inside ``shard_map``;
+  * ``repro.core.distributed.solve_sharded`` / ``solve_sharded_2d``: the
+    dense solvers under ``shard_map``.
 
 Every function here is shape-polymorphic over a trailing RHS axis: state is
 ``(J, n)`` for one right-hand side or ``(J, n, k)`` for a k-system batch.
@@ -29,13 +42,18 @@ def _match_rhs(bvecs: jnp.ndarray, x: jnp.ndarray) -> jnp.ndarray:
     return bvecs
 
 
+def block_residual(blocks: jnp.ndarray, bvecs: jnp.ndarray, x: jnp.ndarray):
+    """Per-block residual vectors A_j x − b_j, (J, p) or (J, p, k)."""
+    return jnp.einsum(
+        "jpn,n...->jp...", blocks, x, precision=_HIGHEST
+    ) - _match_rhs(bvecs, x)
+
+
 def block_residual_sq(blocks: jnp.ndarray, bvecs: jnp.ndarray, x: jnp.ndarray):
     """Global residual ||A x − b||² computed block-wise (no A reassembly).
 
     Scalar for x (n,); per-system vector (k,) for a batched x (n, k)."""
-    r = jnp.einsum(
-        "jpn,n...->jp...", blocks, x, precision=_HIGHEST
-    ) - _match_rhs(bvecs, x)
+    r = block_residual(blocks, bvecs, x)
     return jnp.sum(r * r, axis=(0, 1))
 
 
@@ -45,6 +63,200 @@ def _block_col(v, ndim: int):
     if getattr(v, "ndim", 0) >= 1:
         return v.reshape(v.shape + (1,) * (ndim - v.ndim))
     return v
+
+
+def dynamics(gamma, eta, ndim: int):
+    """The eq. (6)/(7) coefficients for ``ndim``-rank block state:
+    ``(γ, η, η̄, per_block)``.
+
+    ``gamma`` is a scalar or a per-block ``(J,)`` vector; ``eta`` a scalar,
+    a ``(J,)`` vector, or the pair ``(η_j, η̄)`` that a sharded caller
+    passes, its η̄ taken over ALL blocks (a shard holds only its own η_j).
+    Vectors come back shaped to broadcast against the state."""
+    eta, eta_bar = eta if isinstance(eta, tuple) else (eta, None)
+    per_block = (
+        eta_bar is not None
+        or getattr(gamma, "ndim", 0) >= 1
+        or getattr(eta, "ndim", 0) >= 1
+    )
+    if eta_bar is None:
+        eta_bar = jnp.mean(eta) if getattr(eta, "ndim", 0) >= 1 else eta
+    return _block_col(gamma, ndim), _block_col(eta, ndim), eta_bar, per_block
+
+
+def identity(a):
+    return a
+
+
+def projection(apply_fn: Callable[[jnp.ndarray], jnp.ndarray]):
+    """The ``consensus_scan`` ``project`` hook of a projector applied as a
+    function of the stacked differences, ``apply_fn(v)_j = P_j v_j``."""
+    return lambda xs, xbar, aux, active: (apply_fn(xbar[None] - xs), None)
+
+
+def _coord_mean(a):
+    return jnp.mean(a, axis=0)
+
+
+def consensus_scan(
+    x0s: jnp.ndarray,  # (J, n[, k]) this caller's per-block initial iterates
+    xbar0: jnp.ndarray,  # (n[, k]) eq. (5) average, or a warm start
+    project: Callable,  # (xs, x̄, aux, active) -> (P_j(x̄ − x_j), mid)
+    residual: Callable | None = None,  # (x̄, aux) -> A_j x̄ − b_j per block
+    *,
+    gamma,
+    eta,
+    num_epochs: int,
+    aux=(),  # the caller's own carried state
+    advance: Callable | None = None,  # (mid, x̄⁺, q⁺) -> (aux⁺, counts)
+    counts: dict | None = None,  # the initial entry's per-epoch counters
+    pmean: Callable = identity,  # cross-shard mean of a shard-local value
+    psum: Callable = identity,  # cross-shard sum of a shard-local value
+    coord_mean: Callable = _coord_mean,  # mean over x̄'s coordinates
+    x_ref: jnp.ndarray | None = None,
+    tol2: float | None = None,  # freeze columns with residual_sq <= tol2
+    block_history: bool = False,
+    avg_every: int = 1,
+    compress: str | None = None,  # None | "bf16_delta"
+    straggler_prob: float = 0.0,
+    keys: jnp.ndarray | None = None,  # (num_epochs, 2) straggler PRNG keys
+):
+    """Paper eqs. (6)–(7) for ``num_epochs`` epochs, as one ``lax.scan``.
+    Returns ``(x̄_final, history)``.
+
+    **Epoch.** ``project`` returns P_j(x̄ − x_j) for each of the caller's
+    blocks (plus ``mid``, anything it hands on to ``advance``); eq. (6)
+    steps x_j ← x_j + γ_j·P_j(x̄ − x_j), and eq. (7) averages
+    x̄⁺ = η·q⁺ + (1−η)·x̄ with q⁺ = ``pmean``(mean_j x_j⁺) the global block
+    mean. Per-block dynamics (``dynamics``) weight the mean instead:
+    q⁺ = mean_j(η_j·x_j⁺) and x̄⁺ = q⁺ + (1−η̄)·x̄, which reduces EXACTLY to
+    the scalar form when all η_j are equal; scalar inputs keep the scalar
+    program. ``advance(mid, x̄⁺, q⁺)`` then returns the caller's next
+    ``aux`` and the epoch's counters (e.g. Gram solve depths). The hooks
+    ``pmean``/``psum`` are the only places cross-shard information
+    enters: identity on one host, ``lax.pmean``/``lax.psum`` over the
+    block axes under ``shard_map``, so the average costs one n·k
+    collective an epoch and the residual one k-length one.
+
+    **Average variants.** ``compress="bf16_delta"`` halves the consensus
+    all-reduce payload by communicating the DELTA mean(x)−x̄ in bf16
+    (x̄ += η·Δ; η_j folded into Δ under per-block dynamics). The
+    quantization error is relative to the shrinking delta, so the
+    trajectory matches f32 to the final MSE (tests/test_core_solvers.py),
+    unlike quantizing x̄ itself, which floors at bf16 ULP.
+    ``straggler_prob = q > 0`` publishes each block's update only with
+    probability 1−q an epoch (one draw per block from ``keys[t]``, shared
+    by its RHS columns) and averages the last published states: stale
+    consensus, which the η-EMA absorbs. ``avg_every > 1`` runs the
+    average only every that many epochs; between averages the blocks
+    take local projection steps against the stale x̄.
+
+    **History.** Each row holds, of the x̄ (and ``aux``) an epoch ends
+    with: ``mse`` to ``x_ref`` (``coord_mean`` over coordinates; the paper's
+    Fig. 2 metric), ``residual_sq`` = ``psum``(Σ ||A_j x̄ − b_j||²) when a
+    ``residual`` is given, the per-block ``block_residual_sq`` (J_loc[, k])
+    with ``block_history`` (the same squares, no second residual pass);
+    plus the epoch's ``counts``. Rows stack to ``(E,)`` or ``(E, k)``;
+    ``history["initial"]`` is the row of x̄₀ with the initial ``counts``.
+
+    **tol.** ``tol2`` arms the masked in-scan early exit: a column whose
+    residual of the x̄ the epoch STARTS from (the carried row) satisfies
+    ``residual_sq <= tol2`` freezes — its whole state (x_j, x̄, the
+    published states, ``aux``) stops updating under a ``jnp.where`` mask
+    and its counters read 0 — while the batch keeps its one compiled
+    shape. Once EVERY column is frozen the epoch body short-circuits
+    under a ``lax.cond``: no projection and no residual pass, the state
+    passes through and the row repeats the carried one with zero
+    counters, so results and history are those of the masked scan and
+    ``live_epochs`` counts the epochs that ran the body. Without ``tol2``
+    no mask or cond is traced.
+    """
+    if (tol2 is not None or block_history) and residual is None:
+        raise ValueError("tol and block_history need the residual")
+    gam, eta_col, eta_bar, per_block = dynamics(gamma, eta, x0s.ndim)
+    counts = {} if counts is None else counts
+
+    def row_of(xbar, aux):
+        row = {}
+        if x_ref is not None:
+            ref = x_ref[..., None] if xbar.ndim > x_ref.ndim else x_ref
+            d = xbar - ref
+            row["mse"] = coord_mean(d * d)
+        if residual is not None:
+            r = residual(xbar, aux)
+            sq = r * r
+            row["residual_sq"] = psum(jnp.sum(sq, axis=(0, 1)))
+            if block_history:
+                row["block_residual_sq"] = jnp.sum(sq, axis=1)
+        return row
+
+    def average(pub, xbar):  # eq. (7) -> (x̄⁺, q⁺)
+        if compress == "bf16_delta":
+            d = pub - xbar[None]
+            if per_block:
+                d = eta_col * d
+            q = pmean(jnp.mean(d, axis=0).astype(jnp.bfloat16))
+            q = q.astype(xbar.dtype)
+            return (xbar + q if per_block else xbar + eta * q), q
+        if per_block:
+            q = pmean(jnp.mean(eta_col * pub, axis=0))
+            return q + (1.0 - eta_bar) * xbar, q
+        q = pmean(jnp.mean(pub, axis=0))
+        return eta * q + (1.0 - eta) * xbar, q
+
+    def epoch(state, active, t):
+        xs, xbar, pub, aux = state
+        pv, mid = project(xs, xbar, aux, active)
+        xs_new = xs + gam * pv  # eq. (6), every block in parallel
+        if straggler_prob > 0.0:
+            shape = (xs.shape[0],) + (1,) * (xs.ndim - 1)
+            alive = jax.random.uniform(keys[t], shape) >= straggler_prob
+            alive = alive.astype(xs.dtype)
+            pub = alive * xs_new + (1.0 - alive) * pub
+            xbar_new, q = average(pub, xbar)
+        else:
+            xbar_new, q = average(xs_new, xbar)
+        if avg_every > 1:
+            xbar_new = jnp.where((t + 1) % avg_every == 0, xbar_new, xbar)
+        aux_new, out = (aux, {}) if advance is None else advance(
+            mid, xbar_new, q
+        )
+        new = (xs_new, xbar_new, pub, aux_new)
+        if active is not None:
+            new = jax.tree.map(
+                lambda a, b: jnp.where(active, a, b), new, state
+            )
+            out = jax.tree.map(lambda c: jnp.where(active, c, 0), out)
+        return new, {**row_of(new[1], new[3]), **out}
+
+    row0 = {**row_of(xbar0, aux), **counts}
+    # the published states are carried only when stragglers make them lag
+    init = (x0s, xbar0, x0s if straggler_prob > 0.0 else (), aux)
+    if tol2 is None:
+        def body(state, t):
+            return epoch(state, None, t)
+    else:
+        # the carry also holds the last row, whose residual arms the mask;
+        # an all-frozen epoch would recompute that row from an unchanged
+        # x̄, so it repeats it instead
+        def live(carry, t):
+            state, row = carry
+            state, row = epoch(state, row["residual_sq"] > tol2, t)
+            return (state, row), row
+
+        def frozen(carry, t):
+            idle = jax.tree.map(jnp.zeros_like, counts)
+            return carry, {**carry[1], **idle}
+
+        def body(carry, t):
+            any_active = jnp.any(carry[1]["residual_sq"] > tol2)
+            return jax.lax.cond(any_active, live, frozen, carry, t)
+
+        init = (init, row0)
+    carry, hist = jax.lax.scan(body, init, jnp.arange(num_epochs))
+    xbar = (carry if tol2 is None else carry[0])[1]
+    hist["initial"] = row0
+    return xbar, hist
 
 
 def run_consensus(
@@ -62,167 +274,47 @@ def run_consensus(
     tol: float | None = None,  # masked per-column early exit
     block_history: bool = False,  # per-block residual diagnostics
 ):
-    """Paper eqs. (5)–(7). Returns (x̄_final, history dict).
+    """Paper eqs. (5)–(7) on one host with a dense block projector.
+    Returns (x̄_final, history dict).
 
-    history carries per-epoch MSE to ``x_ref`` (paper Fig. 2 metric) and the
-    global residual when (blocks, bvecs) are supplied; with a batched
-    ``(J, n, k)`` input both metrics are per-system ``(k,)`` rows.
-
-    ``block_history=True`` additionally records the PER-BLOCK residual
-    ``history["block_residual_sq"]`` — ``(J,)`` per epoch, ``(J, k)``
-    batched — the convergence diagnostic ``repro.obs.convergence``
-    summarizes (which block drags, per-block decay rates). It reuses the
-    residual pass's per-block partials, so enabling it adds reductions
-    only, never another projector application; disabled (the default) the
-    program is untouched.
-
-    ``tol`` arms the masked in-scan early exit: a column whose residual
-    reaches ``residual_sq <= tol²`` FREEZES — its xs/x̄ columns stop
-    updating under a ``jnp.where`` mask — while the batch keeps its one
-    compiled shape, so one slow column no longer drags converged
-    batchmates through further consensus motion. The mask reads the
-    residual carried from the previous epoch (no extra einsum). Requires
-    (blocks, bvecs); the frozen column's residual history simply repeats
-    its converged value, so ``iterations_to_tol`` reports are unchanged.
-    Once EVERY column is frozen the epoch body short-circuits under a
-    ``lax.cond``: no projector apply and no residual pass, the state
-    passes through, and the history row repeats the carried one (the
-    last computed from this same x̄), so results and history are those of
-    the masked scan. ``live_epochs`` counts the epochs that ran the body.
-    Without ``tol`` no cond is traced.
-
-    ``compress="bf16_delta"`` halves the consensus all-reduce payload by
-    communicating the DELTA mean(x)−x̄ in bf16 (eq. 7 rewritten as
-    x̄ += η·Δ). The quantization error is relative to the shrinking delta,
-    so the trajectory matches f32 to the final MSE (validated in
-    tests/test_core_solvers.py; EXPERIMENTS.md §Perf solver iteration 3) —
-    unlike quantizing x̄ itself, which floors at bf16 ULP.
-
-    ``gamma``/``eta`` accept per-block ``(J,)`` vectors (heterogeneity-aware
-    dynamics): eq. (6) steps block j with γ_j and eq. (7) becomes the
-    weighted mean x̄⁺ = mean_j(η_j·xs_j⁺) + (1−η̄)·x̄ with η̄ = mean(η_j),
-    which reduces EXACTLY to the scalar form when all η_j are equal. With
-    scalar inputs the program is the historical one, bit for bit.
-
-    ``avg_every > 1`` is a beyond-paper collective optimization: the
-    consensus average (the only cross-worker collective) runs every k-th
-    epoch; between averages workers take local projection steps against the
-    stale x̄. Cuts the all-reduce count by k× — at 512+ chips the per-epoch
-    n-vector psum is the latency floor of the whole algorithm
-    (EXPERIMENTS.md §Perf, solver)."""
+    ``apply_fn`` applies every P_j (materialized, or v − Wᵀ(W v)); the
+    residual, when ``(blocks, bvecs)`` are supplied, is one einsum over
+    the row blocks after each epoch's update. ``xbar0`` replaces the
+    eq. (5) average (a warm start; an unbatched x̄₀ broadcasts over the
+    batch). ``tol`` (which needs the residual) arms the masked early exit
+    and the all-frozen skip, ``block_history`` records the per-block
+    residual rows that ``repro.obs.convergence`` summarizes; both, with
+    ``gamma``/``eta`` as per-block ``(J,)`` vectors, ``avg_every`` and
+    ``compress``, are ``consensus_scan``'s."""
     if xbar0 is None:
         xbar0 = jnp.mean(x0s, axis=0)  # eq. (5)
     elif xbar0.ndim < x0s.ndim - 1:
         xbar0 = jnp.broadcast_to(xbar0[..., None], x0s.shape[1:])
-    if tol is not None and (blocks is None or bvecs is None):
-        raise ValueError("tol early exit needs (blocks, bvecs) for residuals")
+    residual = None
+    if blocks is not None and bvecs is not None:
+        def residual(xbar, aux):
+            return block_residual(blocks, bvecs, xbar)
 
-    if block_history and (blocks is None or bvecs is None):
-        raise ValueError("block_history needs (blocks, bvecs) for residuals")
-
-    def metrics(xbar):
-        out = {}
-        if x_ref is not None:
-            ref = x_ref[..., None] if xbar.ndim > x_ref.ndim else x_ref
-            d = xbar - ref
-            out["mse"] = jnp.mean(d * d, axis=0)
-        if blocks is not None and bvecs is not None:
-            if block_history:
-                r = (
-                    jnp.einsum(
-                        "jpn,n...->jp...", blocks, xbar, precision=_HIGHEST
-                    )
-                    - _match_rhs(bvecs, xbar)
-                )
-                per_block = jnp.sum(r * r, axis=1)  # (J,) or (J, k)
-                out["block_residual_sq"] = per_block
-                out["residual_sq"] = jnp.sum(per_block, axis=0)
-            else:
-                out["residual_sq"] = block_residual_sq(blocks, bvecs, xbar)
-        return out
-
-    init_metrics = metrics(xbar0)
-
-    per_block = (
-        getattr(gamma, "ndim", 0) >= 1 or getattr(eta, "ndim", 0) >= 1
+    return consensus_scan(
+        x0s, xbar0, projection(apply_fn), residual,
+        gamma=gamma, eta=eta, num_epochs=num_epochs, x_ref=x_ref,
+        tol2=None if tol is None else tol * tol,
+        block_history=block_history, avg_every=avg_every, compress=compress,
     )
-    gam = _block_col(gamma, x0s.ndim)
-    if per_block:
-        eta_col = _block_col(eta, x0s.ndim)
-        eta_bar = (
-            jnp.mean(eta) if getattr(eta, "ndim", 0) >= 1 else eta
-        )
-
-    def step(xs, xbar, resid, t):
-        xs_new = xs + gam * apply_fn(xbar[None] - xs)  # eq. (6), parallel j
-        do_avg = (t + 1) % avg_every == 0
-        if compress == "bf16_delta":
-            if per_block:  # Δ = mean(η_j (xs_j − x̄)), η folded into the wire
-                delta = jnp.mean(eta_col * (xs_new - xbar[None]), axis=0)
-                delta = delta.astype(jnp.bfloat16).astype(xbar.dtype)
-                xbar_new = xbar + delta
-            else:
-                delta = jnp.mean(xs_new - xbar[None], axis=0)  # wire payload
-                delta = delta.astype(jnp.bfloat16).astype(xbar.dtype)
-                xbar_new = xbar + eta * delta  # eq. (7), delta form
-        elif per_block:  # eq. (7), η_j-weighted mean (reduces to scalar form)
-            xbar_new = (
-                jnp.mean(eta_col * xs_new, axis=0) + (1.0 - eta_bar) * xbar
-            )
-        else:
-            xbar_new = (
-                eta * jnp.mean(xs_new, axis=0) + (1.0 - eta) * xbar
-            )  # eq. (7)
-        xbar_new = jnp.where(do_avg, xbar_new, xbar)
-        if tol is not None:
-            # residual of the x̄ this epoch STARTED from, carried from the
-            # previous metrics pass — frozen columns stop moving entirely
-            active = resid > tol * tol  # (k,) batched, scalar otherwise
-            xs_new = jnp.where(active, xs_new, xs)
-            xbar_new = jnp.where(active, xbar_new, xbar)
-        return xs_new, xbar_new, metrics(xbar_new)
-
-    if tol is None:
-        def epoch(carry, t):
-            xs, xbar, out = step(*carry, None, t)
-            return (xs, xbar), out
-
-        init = (x0s, xbar0)
-    else:
-        # the carry holds the last history row, whose residual arms the
-        # mask; an all-frozen epoch would recompute that row from an
-        # unchanged x̄, so it repeats it instead
-        def live(carry, t):
-            xs, xbar, row = carry
-            xs, xbar, out = step(xs, xbar, row["residual_sq"], t)
-            return (xs, xbar, out), out
-
-        def frozen(carry, t):
-            return carry, carry[2]
-
-        def epoch(carry, t):
-            any_active = jnp.any(carry[2]["residual_sq"] > tol * tol)
-            return jax.lax.cond(any_active, live, frozen, carry, t)
-
-        init = (x0s, xbar0, init_metrics)
-    (_, xbar, *_), hist = jax.lax.scan(epoch, init, jnp.arange(num_epochs))
-    hist["initial"] = init_metrics
-    return xbar, hist
 
 
 def live_epochs(hist: dict, num_epochs: int, tol: float | None) -> int:
-    """Epochs in which a consensus scan ran its epoch body (``run_consensus``
-    here, ``matfree.consensus_epochs`` on the matrix-free path), counted on
-    the host from the history a solve returns.
+    """Epochs in which ``consensus_scan`` ran its epoch body, counted on the
+    host from the history a solve returns.
 
     Without ``tol`` every epoch is live. With it, epoch t runs the body iff
     some column's residual at the epoch's start exceeds ``tol²`` — the
     program's own freeze predicate, applied to the very residuals it
     emitted (entry t − 1 of ``residual_sq``, the initial one for t = 0) in
-    their own dtype. The matrix-free frozen branch also writes zero inner
-    depths, but a live PCG epoch whose warm-started inner solves start
-    below their tolerance reports zero depth too, so ``inner_iters`` alone
-    would undercount.
+    their own dtype. The frozen branch also writes zero counters (the
+    matrix-free inner depths), but a live PCG epoch whose warm-started
+    inner solves start below their tolerance reports zero depth too, so
+    ``inner_iters`` alone would undercount.
     """
     if tol is None:
         return num_epochs

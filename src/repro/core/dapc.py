@@ -16,7 +16,7 @@ amortize it across right-hand sides:
 Two projector forms:
   * materialized — paper-faithful: dense n×n P_j built per block.
   * implicit — beyond-paper: P v = v − Wᵀ(W v) (two tall-skinny MXU
-    matmuls; O(np) memory; see DESIGN.md §1.2).
+    matmuls; O(np) memory; README §Solver API).
 ``projector_form`` picks the one that reads fewer bytes an epoch from the
 block shape; ``materialize_p=True``/``False`` pins it (``solve_dapc``, the
 paper-faithful one-shot path, pins the materialized form by default).

@@ -1,4 +1,4 @@
-"""SPMD distributed DAPC/APC via ``jax.shard_map`` (DESIGN.md §2, §7).
+"""SPMD distributed DAPC/APC via ``jax.shard_map``.
 
 The paper's Dask task graph maps onto a static SPMD program:
 
@@ -6,7 +6,9 @@ The paper's Dask task graph maps onto a static SPMD program:
     blocks per shard; ``vmap`` over the local blocks),
   * consensus average → ``lax.pmean`` over those axes (hierarchical ICI/DCN
     all-reduce instead of a scheduler round-trip),
-  * epochs            → ``lax.scan`` inside one jit.
+  * epochs            → ``repro.core.consensus.consensus_scan`` inside one
+    jit: both solvers here set up on their shard and hand the shared epoch
+    loop their projection, their residual and ``pmean``/``psum`` hooks.
 
 Beyond-paper features:
 
@@ -32,8 +34,9 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.core.dapc import setup_decomposed
+from repro.core import consensus
 from repro.core.apc import setup_classical
+from repro.core.dapc import setup_decomposed
 
 
 def _pmean(x, axes):
@@ -89,16 +92,17 @@ def solve_sharded(
     columns at once (one mask per block, as a real slow worker would).
     """
     block_axes = tuple(block_axes)
-    num_blocks = blocks.shape[0]
     spec_in = P(block_axes)
     q = float(straggler_prob)
+
+    pmean = functools.partial(_pmean, axes=block_axes)
+    psum = functools.partial(_psum, axes=block_axes)
 
     @functools.partial(
         jax.shard_map,
         mesh=mesh,
         in_specs=(spec_in, spec_in, P(None) if x_ref is not None else P()),
-        out_specs=(P(), {"mse": P(), "residual_sq": P()} if x_ref is not None
-                   else {"residual_sq": P()}),
+        out_specs=(P(), P()),
     )
     def run(local_blocks, local_bvecs, ref):
         # Algorithm 1 steps 2–3, vmapped over this shard's blocks; all
@@ -112,41 +116,19 @@ def solve_sharded(
             x0s, Ps = setup_classical(local_blocks, local_bvecs, mode)
             apply_fn = lambda v: jnp.einsum("jmn,jn...->jm...", Ps, v)
 
-        def metrics(xbar):
-            r = jnp.einsum("jpn,n...->jp...", local_blocks, xbar) - local_bvecs
-            out = {"residual_sq": _psum(jnp.sum(r * r, axis=(0, 1)), block_axes)}
-            if x_ref is not None:
-                d = xbar - ref
-                out["mse"] = jnp.mean(d * d, axis=0)
-            return out
+        def residual(xbar, aux):
+            return jnp.einsum("jpn,n...->jp...", local_blocks, xbar) - local_bvecs
 
-        xbar = _pmean(jnp.mean(x0s, axis=0), block_axes)  # eq. (5)
-        published = x0s
-
-        def step(carry, key):
-            xs, pub, xbar = carry
-            xs = xs + gamma * apply_fn(xbar[None] - xs)  # eq. (6)
-            if q > 0.0:  # straggler simulation: stale contributions — one
-                # mask per block, shared across the RHS columns it serves
-                alive = (
-                    jax.random.uniform(key, (xs.shape[0],) + (1,) * (xs.ndim - 1))
-                    >= q
-                ).astype(xs.dtype)
-                pub = alive * xs + (1.0 - alive) * pub
-            else:
-                pub = xs
-            if compress == "bf16_delta":
-                local = jnp.mean(pub - xbar[None], axis=0)
-                delta = _pmean(local.astype(jnp.bfloat16), block_axes)
-                xbar = xbar + eta * delta.astype(xbar.dtype)  # eq. (7), Δ form
-            else:
-                mean_pub = _pmean(jnp.mean(pub, axis=0), block_axes)
-                xbar = eta * mean_pub + (1.0 - eta) * xbar  # eq. (7)
-            return (xs, pub, xbar), metrics(xbar)
-
-        keys = _epoch_keys(seed, block_axes, num_epochs)
-        (_, _, xbar), hist = jax.lax.scan(step, (x0s, published, xbar), keys)
-        return xbar, hist
+        return consensus.consensus_scan(
+            x0s, pmean(jnp.mean(x0s, axis=0)),  # eq. (5)
+            consensus.projection(apply_fn), residual,
+            gamma=gamma, eta=eta, num_epochs=num_epochs,
+            pmean=pmean, psum=psum,
+            x_ref=ref if x_ref is not None else None,
+            compress=compress, straggler_prob=q,
+            # one mask per block, shared across the RHS columns it serves
+            keys=_epoch_keys(seed, block_axes, num_epochs) if q > 0.0 else None,
+        )
 
     ref = (
         jnp.asarray(x_ref, blocks.dtype)
@@ -199,6 +181,9 @@ def solve_sharded_2d(
     if n % col_shards:
         raise ValueError(f"n={n} not divisible by {col_axis}={col_shards}")
 
+    pmean = functools.partial(_pmean, axes=block_axes)
+    psum = functools.partial(_psum, axes=block_axes)
+
     @functools.partial(
         jax.shard_map,
         mesh=mesh,
@@ -207,11 +192,7 @@ def solve_sharded_2d(
             P(block_axes),
             P(col_axis) if x_ref is not None else P(),
         ),
-        out_specs=(
-            P(col_axis),
-            {"mse": P(), "residual_sq": P()} if x_ref is not None
-            else {"residual_sq": P()},
-        ),
+        out_specs=(P(col_axis), P()),
     )
     def run(bt_loc, b_loc, ref_loc):
         # bt_loc: (J_loc, n_loc, p); b_loc: (J_loc, p[, k])
@@ -226,30 +207,20 @@ def solve_sharded_2d(
             u = _psum(jnp.einsum("jnp,jn...->jp...", Qs, v), (col_axis,))
             return v - jnp.einsum("jnp,jp...->jn...", Qs, u)
 
-        def metrics(xbar_loc):
-            # residual: A_j x = psum_model(B_locᵀ x_loc)
+        def residual(xbar_loc, aux):  # A_j x = psum_model(B_locᵀ x_loc)
             ax = _psum(
                 jnp.einsum("jnp,n...->jp...", bt_loc, xbar_loc), (col_axis,)
             )
-            r = ax - b_loc
-            out = {"residual_sq": _psum(jnp.sum(r * r, axis=(0, 1)), block_axes)}
-            if x_ref is not None:
-                d = xbar_loc - ref_loc
-                out["mse"] = _pmean(jnp.mean(d * d, axis=0), (col_axis,))
-            return out
+            return ax - b_loc
 
-        xbar = _pmean(jnp.mean(x0s, axis=0), block_axes)
-
-        def step(carry, _):
-            xs, xbar = carry
-            xs = xs + gamma * apply_fn(xbar[None] - xs)
-            xbar = eta * _pmean(jnp.mean(xs, axis=0), block_axes) + (
-                1.0 - eta
-            ) * xbar
-            return (xs, xbar), metrics(xbar)
-
-        (_, xbar), hist = jax.lax.scan(step, (x0s, xbar), None, length=num_epochs)
-        return xbar, hist
+        return consensus.consensus_scan(
+            x0s, pmean(jnp.mean(x0s, axis=0)),  # eq. (5)
+            consensus.projection(apply_fn), residual,
+            gamma=gamma, eta=eta, num_epochs=num_epochs,
+            pmean=pmean, psum=psum,
+            coord_mean=lambda a: _pmean(jnp.mean(a, axis=0), (col_axis,)),
+            x_ref=ref_loc if x_ref is not None else None,
+        )
 
     ref = (
         jnp.asarray(x_ref, blocks_t.dtype)

@@ -28,7 +28,8 @@ under ``DIRECT_GRAM_BYTES`` and "pcg" beyond.
 
 The HOT-LOOP STRUCTURE (this file's perf contract) makes one outer epoch a
 single fused pass over the forward tiles plus the inner Gram solve, by
-carrying the probe ``z_j = A_j x̄`` through the ``lax.scan``:
+carrying the probe ``z_j = A_j x̄`` through the epoch loop
+(``repro.core.consensus.consensus_scan``):
 
   * ``z`` doubles as the residual metric AND the projection input: the
     paper's iterates keep A_j x_j = b_j invariant (every update moves
@@ -70,13 +71,13 @@ effective inner iteration counts are recorded every epoch in
 application) — the matfree analogue of the dense path's per-column epoch
 reporting.
 
-``solve(..., tol=...)`` arms the masked in-scan early exit: each epoch the
-per-column residual (read off the carried probe ``z``) gates the consensus
-update under ``jnp.where``, so converged columns freeze — their projector
-work stops, and for the PCG path they stop driving the inner-CG depth —
-while the batch keeps its one compiled shape; once EVERY column is frozen
-the whole epoch body short-circuits to a carry-through (``lax.cond``), so
-trailing epochs cost vector ops only.
+``solve(..., tol=...)`` arms the loop's masked in-scan early exit: each
+epoch the per-column residual (read off the carried probe ``z``) gates the
+consensus update under ``jnp.where``, so converged columns freeze — their
+projector work stops, and for the PCG path they stop driving the inner-CG
+depth — while the batch keeps its one compiled shape; once EVERY column is
+frozen the whole epoch body short-circuits to a carry-through
+(``lax.cond``), so trailing epochs cost vector ops only.
 """
 from __future__ import annotations
 
@@ -88,7 +89,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core.consensus import live_epochs
+from repro.core import consensus
 from repro.core.prepared import SolveOptions, SolveResult
 from repro.core.spectra import (
     dynamics_arrays as _dynamics_arrays,
@@ -242,15 +243,6 @@ def _gram_pinv(op: PartitionedBSR, dtype) -> np.ndarray:
     return out.astype(dtype)
 
 
-def _local_block_mean(a: jnp.ndarray) -> jnp.ndarray:
-    """(J, n, k) block stack -> (n, k) mean. Single-host: J is ALL blocks."""
-    return jnp.mean(a, axis=0)
-
-
-def _identity(a):
-    return a
-
-
 def consensus_epochs(
     op: PartitionedBSR,
     diag_inv: jnp.ndarray,
@@ -267,80 +259,47 @@ def consensus_epochs(
     warm_start: bool,
     tol2: float | None,
     num_epochs: int,
-    block_mean=_local_block_mean,
-    reduce_sum=_identity,
+    pmean=consensus.identity,
+    psum=consensus.identity,
     x0=None,  # (n, k) predicted solution, or masked pair ((n, k), (k,))
     block_history: bool = False,  # per-block residual diagnostics
 ):
-    """The fused-projection consensus iteration, mesh-agnostic.
+    """The fused-projection consensus iteration, mesh-agnostic: the
+    matrix-free caller of ``repro.core.consensus.consensus_scan``.
 
     ``op``/``bvecs`` hold whatever set of partition blocks this caller owns
     — ALL J blocks on a single host, or one shard's J_loc blocks inside a
     ``shard_map`` (repro.core.matfree_sharded). The two reduction hooks
-    are the only places global information enters:
+    are the only places global information enters, and the loop applies
+    them: ``pmean`` to the local block mean of the consensus average
+    (eqs. 5/7), the ONE n·k-payload collective of a sharded epoch, and
+    ``psum`` to the k-length residual partial sums. A sharded caller with
+    no in-scan use for the global residual (no ``tol``) keeps ``psum`` the
+    identity and collapses the emitted partials after the scan instead,
+    dropping the epoch to ONE collective.
 
-      * ``block_mean`` — (J_loc, n, k) -> GLOBAL block mean (n, k). The
-        consensus average of eqs. (5)/(7); sharded callers pass
-        mean-then-``pmean``, the ONE n·k-payload collective of an epoch.
-      * ``reduce_sum`` — per-shard residual partial sums -> global (k,).
-        The k-length residual ``psum``; a sharded caller with no in-scan
-        use for the global residual (no ``tol``) may pass identity and
-        collapse the emitted partials after the scan instead, dropping
-        the epoch to ONE collective.
+    What this function owns is what is matrix-free (module docstring):
+    the initial iterates, both Gram solvers, the fused tile pass, and the
+    carried state ``aux = (q, w, z, y)`` —
 
-    Everything else — both Gram solvers, the fused tile pass, the balance
-    permutation — is strictly block-local, which is what makes the sharded
-    epoch's collective payload at most n·k + k. The inner-CG depth counts
-    in ``history["inner_iters"]`` are this caller's own blocks'; a sharded
-    caller takes their max over shards after the scan. The PCG path's
-    carried ``w_j = A_j x_j`` holds A_j x_j = b_j + γ·r_cg, the last inner
-    residual scaled by the step, and its epoch folds the defect b_j − w_j
-    back into the Gram right-hand side (module docstring) — block-local
-    too, so the correction adds nothing to the collective budget.
+      * ``q``, the global block mean eq. (7) formed last epoch (η_j-
+        weighted under per-block dynamics): the next epoch's fused operand
+        KNOWN needs it, and recomputing it would double the payload;
+      * ``w_j = A_j x_j``, which the PCG path's defect fold reads;
+      * the probe ``z_j = A_j x̄``, which is both the Gram right-hand side
+        and the residual the loop records;
+      * the last Gram solution ``y``, the PCG warm start.
 
-    To keep that bound at ONE consensus collective, the global block mean
-    ``q = mean_j x_j`` is carried through the scan: the end-of-epoch mean
-    that forms x̄⁺ (eq. 7) is the same value the NEXT epoch's fused operand
-    KNOWN needs, so recomputing it at epoch start would double the payload.
-    Carrying it is float-identical to the historical recompute (same op on
-    the same carried ``xs``).
-
-    ``block_history=True`` additionally emits the per-block residual
-    ``history["block_residual_sq"]`` each epoch, read off the SAME carried
-    probe ``z`` the scalar residual uses — a (J_loc, k) row-axis reduction,
-    no extra tile pass. Sharded callers ride it through their ``out_specs``
-    exactly like the residual partials (each shard's (J_loc, k) rows
-    concatenate to the global (J, k) on the host), so enabling it adds NO
-    extra collective to the epoch; disabled, the program is untouched.
-
-    Per-block dynamics (heterogeneity-aware): ``gamma`` may be a
-    ``(J_loc,)`` vector and ``eta`` the pair ``(eta_vec (J_loc,), eta_bar
-    scalar)``. Eq. (7) becomes the η_j-weighted mean x̄⁺ = mean_j(η_j xs_j⁺)
-    + (1−η̄)x̄ — the carried ``q`` then holds the WEIGHTED mean, so the
-    epoch still pays exactly the one ``block_mean`` collective: each shard
-    weights its local blocks by its η_j slice BEFORE the mean, and η̄
-    arrives precomputed as a replicated scalar (zero new collectives).
-    Scalar inputs keep the historical program bit for bit.
+    The inner-CG depth counts in ``history["inner_iters"]`` are this
+    caller's own blocks'; a sharded caller takes their max over shards
+    after the scan. ``block_history`` rows read the same probe, no extra
+    tile pass; sharded callers ride them through their ``out_specs``.
 
     Returns ``(x̄ (n, k), history)`` with the same history contract as
     ``MatrixFreePreparedSolver.solve`` documents.
     """
     ones = jnp.ones(bvecs.shape[-1], jnp.int32)
-
-    per_block = isinstance(eta, tuple) or getattr(gamma, "ndim", 0) >= 1
-    if per_block:
-        eta_vec, eta_bar = eta if isinstance(eta, tuple) else (eta, eta)
-        eta_col = (
-            eta_vec[:, None, None]
-            if getattr(eta_vec, "ndim", 0) >= 1 else eta_vec
-        )
-        gam = gamma[:, None, None] if getattr(gamma, "ndim", 0) >= 1 else gamma
-    else:
-        gam = gamma
-
-    def mse(xbar):
-        d = xbar - (ref[..., None] if ref.ndim == 1 else ref)
-        return jnp.mean(d * d, axis=0)
+    gam, eta_col, eta_bar, per_block = consensus.dynamics(gamma, eta, 3)
 
     # eqs. (2-3) matfree: min-norm x_j(0) = A_jᵀ (A_jA_jᵀ)⁻¹ b_j — or, with
     # an ``x0`` warm start (sessions), the PROJECTION of the prediction
@@ -370,109 +329,55 @@ def consensus_epochs(
     # the CG residual hands back w0 = A_j x_j(0) = b_j − r0 for free (an x0
     # shift included); the first epoch folds the defect r0 back in
     w0 = bvecs - r0
-    xbar0 = block_mean(x0s)  # eq. (5)
+    xbar0 = pmean(jnp.mean(x0s, axis=0))  # eq. (5)
     z0 = op.matvec(xbar0, use_kernels)  # probe of x̄_0
+    # per-block: q carries the weighted mean — one extra collective at
+    # INIT only, outside the scan (the per-epoch budget is untouched)
+    q0 = pmean(jnp.mean(eta_col * x0s, axis=0)) if per_block else xbar0
 
-    def live_step(xs, xbar, q, w, z, ywarm, active):
+    def project(xs, xbar, aux, active):
+        q, w, z, ywarm = aux
         u = z - w  # A_j (x̄ − x_j)
         if direct:
             y = jnp.einsum("jqp,jpk->jqk", gram_inv, u, precision=_HIGHEST)
-            used, r = ones, None
+            used, w_new = ones, w
         else:
-            # fold the carried defect b_j − w_j back in, so the step below
-            # lands A_j x_j⁺ on b_j + γ·r and not on w_j + γ·r (see the
-            # module docstring)
+            # fold the carried defect b_j − w_j back in, so the step lands
+            # A_j x_j⁺ on b_j + γ·r and not on w_j + γ·r (module docstring)
             u = u - (bvecs - w) / gam
             y, used, r = _pcg_gram(
                 op, u, diag_inv, inner_iters, inner_tol, use_kernels,
                 warm=ywarm if warm_start else None, active=active,
             )
+            w_new = bvecs + gam * r  # A_j x_j⁺ = b_j + γ·r
         # x̄⁺ = KNOWN − (ηγ/J)·Σ_j A_jᵀy_j in exact arithmetic, and KNOWN
         # needs no transpose product — so the epoch's two tile
         # contractions run in ONE fused pass. The trajectory itself stays
-        # float-CANONICAL (same op order as the dense consensus); KNOWN
-        # only serves as the fused forward operand, and the probe is
-        # patched with the exact float difference x̄⁺ − KNOWN, keeping z
-        # accurate to ULP instead of compounding reassociation noise
-        # across epochs. q is the CARRIED global mean of xs (see above).
-        if per_block:  # q carries the η_j-weighted mean (see docstring);
-            # KNOWN is only the fused linearization point, the probe patch
-            # below restores exactness for any approximation here
+        # float-CANONICAL (the loop's eqs. 6–7); KNOWN only serves as the
+        # fused forward operand, and ``advance`` patches the probe with the
+        # exact float difference x̄⁺ − KNOWN, keeping z accurate to ULP
+        # instead of compounding reassociation noise across epochs.
+        if per_block:  # KNOWN is only a linearization point: the probe
+            # patch restores exactness for any approximation here
             known = q + (1.0 - eta_bar) * xbar
         else:
             known = eta * q + eta * gamma * (xbar - q) + (1.0 - eta) * xbar
         f, g = op.fused_project(known, y, use_kernels)
-        xs_new = xs + gam * (xbar[None] - xs - g)  # eq. (6)
-        # the epoch's consensus collective (η_j-weighted when per-block)
-        q_new = block_mean(eta_col * xs_new) if per_block else block_mean(xs_new)
-        if per_block:
-            xbar_new = q_new + (1.0 - eta_bar) * xbar  # eq. (7), weighted
-        else:
-            xbar_new = eta * q_new + (1.0 - eta) * xbar  # eq. (7)
+        return xbar[None] - xs - g, (known, f, w_new, y, used)
+
+    def advance(mid, xbar_new, q_new):
+        known, f, w_new, y, used = mid
         z_new = f + op.matvec(xbar_new - known, use_kernels)
-        # an exact inner solve keeps the paper's A_j x_j = b_j, so w stays
-        # put; an inexact one leaves A_j x_j⁺ = b_j + γ·r
-        w_new = w if direct else bvecs + gam * r
-        if active is not None:
-            col = active[None]  # (1, k) over (n, k) state
-            blk = active[None, None]  # (1, 1, k) over (J, ·, k)
-            xs_new = jnp.where(blk, xs_new, xs)
-            w_new = jnp.where(blk, w_new, w)
-            z_new = jnp.where(blk, z_new, z)
-            xbar_new = jnp.where(col, xbar_new, xbar)
-            q_new = jnp.where(col, q_new, q)
-            used = jnp.where(active, used, 0)
-        return (xs_new, xbar_new, q_new, w_new, z_new, y), used
+        return (q_new, w_new, z_new, y), {"inner_iters": used}
 
-    def step(carry, _):
-        xs, xbar, q, w, z, ywarm = carry
-        # residual of the CURRENT x̄, read off the carried probe
-        r_sq = (z - bvecs) ** 2
-        resid = reduce_sum(jnp.sum(r_sq, axis=(0, 1)))
-        if tol2 is None:
-            carry, used = live_step(xs, xbar, q, w, z, ywarm, None)
-        else:
-            active = resid > tol2
-            carry, used = jax.lax.cond(
-                jnp.any(active),
-                lambda c: live_step(*c, active),
-                lambda c: (c, jnp.zeros_like(ones)),
-                (xs, xbar, q, w, z, ywarm),
-            )
-        out = {"residual_sq": resid, "inner_iters": used}
-        if block_history:  # shard-local rows; no collective (see docstring)
-            out["block_residual_sq"] = jnp.sum(r_sq, axis=1)
-        if ref is not None:
-            out["mse"] = mse(carry[1])
-        return carry, out
-
-    # per-block: the carried q is the weighted mean — one extra collective
-    # at INIT only, outside the scan (the per-epoch budget is untouched)
-    q_init = block_mean(eta_col * x0s) if per_block else xbar0
-    init = (x0s, xbar0, q_init, w0, z0, jnp.zeros_like(y0))
-    (_, xbar, _, _, z, _), hist = jax.lax.scan(
-        step, init, None, length=num_epochs
+    return consensus.consensus_scan(
+        x0s, xbar0, project, lambda xbar, aux: aux[2] - bvecs,
+        gamma=gamma, eta=eta, num_epochs=num_epochs,
+        aux=(q0, w0, z0, jnp.zeros_like(y0)), advance=advance,
+        counts={"inner_iters": setup_iters},
+        pmean=pmean, psum=psum, x_ref=ref, tol2=tol2,
+        block_history=block_history,
     )
-    # the probe is computed at epoch START, so emitted entry t is the
-    # residual of x̄_t: entry 0 is the "initial" metric and the final x̄
-    # gets one fresh probe after the scan
-    rfin = op.matvec(xbar, use_kernels) - bvecs
-    resid_fin = reduce_sum(jnp.sum(rfin * rfin, axis=(0, 1)))
-    emitted = hist.pop("residual_sq")
-    hist["residual_sq"] = jnp.concatenate([emitted[1:], resid_fin[None]])
-    hist["initial"] = {
-        "residual_sq": emitted[0], "inner_iters": setup_iters,
-    }
-    if block_history:  # same one-epoch shift as the scalar residual
-        emitted_b = hist.pop("block_residual_sq")
-        rb_fin = jnp.sum(rfin * rfin, axis=1)
-        hist["block_residual_sq"] = jnp.concatenate(
-            [emitted_b[1:], rb_fin[None]]
-        )
-        hist["initial"]["block_residual_sq"] = emitted_b[0]
-    if ref is not None:
-        hist["initial"]["mse"] = mse(xbar0)
-    return xbar, hist
 
 
 @dataclasses.dataclass
@@ -724,7 +629,7 @@ class MatrixFreePreparedSolver:
                         lambda a: a[..., 0] if a.ndim and a.shape[-1] == 1
                         else a, hist,
                     )
-            epochs_run = live_epochs(hist, num_epochs, tol)
+            epochs_run = consensus.live_epochs(hist, num_epochs, tol)
             # the direct path applies its Gram inverse once per live epoch
             gram_iters = (
                 epochs_run if self.gram_solver == "direct"

@@ -47,6 +47,7 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from repro.compat import shard_map_unchecked
+from repro.core import consensus
 from repro.core.matfree import MatrixFreePreparedSolver, consensus_epochs
 
 
@@ -177,13 +178,12 @@ class ShardedMatrixFreeSolver(MatrixFreePreparedSolver):
                     warm_start=self.warm_start,
                     tol2=None if tol is None else float(tol) ** 2,
                     num_epochs=num_epochs,
-                    # mean over the LOCAL blocks, pmean over the mesh: the
-                    # global consensus average in ONE n·k collective
-                    block_mean=lambda a: jax.lax.pmean(
-                        jnp.mean(a, axis=0), red
-                    ),
-                    reduce_sum=(
-                        (lambda a: a) if partial_resid
+                    # the loop means over the LOCAL blocks, pmean over the
+                    # mesh: the global consensus average in ONE n·k
+                    # collective
+                    pmean=lambda a: jax.lax.pmean(a, red),
+                    psum=(
+                        consensus.identity if partial_resid
                         else (lambda a: jax.lax.psum(a, red))
                     ),
                     x0=x0,
