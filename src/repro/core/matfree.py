@@ -210,7 +210,8 @@ def _gram_pinv(op: PartitionedBSR, dtype) -> np.ndarray:
     """Per-block dense pseudo-inverse of the Gram shards, (J, p_pad, p_pad),
     as a host array (the caller places it).
 
-    Built host-side in float64 from the (near-diagonal) sparse Gram and
+    Built host-side in float64 from the (near-diagonal) sparse Gram, in
+    whichever form it is stored (``PartitionedBSR.gram_dense``), and
     restricted to the nonsingular sub-block (padding rows — and any exactly
     dependent rows — are annihilated by the pseudo-inverse, matching the CG
     iterates staying pinned at zero there). O(J·p³) once at prepare time.
@@ -222,18 +223,10 @@ def _gram_pinv(op: PartitionedBSR, dtype) -> np.ndarray:
     turns the projector into garbage. Full-rank Grams have no eigenvalues
     near either cutoff, so their inverse is unchanged bit for bit.
     """
-    J, Rp, Sg = op.gram_indices.shape
-    bp = op.gram_data.shape[-2]
-    idx = np.asarray(op.gram_indices)
-    data = np.asarray(op.gram_data, dtype=np.float64)
-    rcond = float(np.finfo(np.asarray(op.gram_data).dtype).eps) * op.p_pad
-    out = np.zeros((J, op.p_pad, op.p_pad), np.float64)
-    for j in range(J):
-        G = np.zeros((Rp, Rp, bp, bp))
-        # padding slots target block 0 with zero data: += keeps them inert
-        np.add.at(G, (np.repeat(np.arange(Rp), Sg), idx[j].ravel()),
-                  data[j].reshape(Rp * Sg, bp, bp))
-        G = G.transpose(0, 2, 1, 3).reshape(op.p_pad, op.p_pad)
+    rcond = float(np.finfo(op.fwd_data.dtype).eps) * op.p_pad
+    dense = op.gram_dense()
+    out = np.zeros_like(dense)
+    for j, G in enumerate(dense):
         live = np.flatnonzero(np.diag(G) > 0)
         if live.size:
             sub = np.linalg.pinv(
@@ -446,6 +439,26 @@ class MatrixFreePreparedSolver:
         """What the dense path's (J, p, n) blocks alone would cost."""
         return self.op.dense_bytes
 
+    @property
+    def num_shards(self) -> int:
+        """Devices the blocks are split over: one on a single host."""
+        return 1
+
+    @property
+    def gram_form(self) -> str:
+        """How a solve applies the Gram product: the PCG path's stored form,
+        "dia" or "ell" (``PartitionedBSR.gram_form``), or "none" on the
+        direct path, which applies the Gram inverses instead."""
+        return "none" if self.gram_solver == "direct" else self.op.gram_form
+
+    @property
+    def gram_bytes(self) -> int:
+        """Bytes one Gram product reads on each device: its share of the
+        stored form (0 on the direct path)."""
+        if self.gram_solver == "direct":
+            return 0
+        return self.op.gram_bytes // self.num_shards
+
     def block_rhs(self, b) -> jnp.ndarray:
         """RHS (m,) or (m, k) -> (J, p_pad, k), plan-aware.
 
@@ -597,7 +610,8 @@ class MatrixFreePreparedSolver:
         tol = None if tol is None else float(tol)
         with span(
             "solve", path=self.path, k=b.shape[1] if batched else 1,
-            num_epochs=num_epochs,
+            num_epochs=num_epochs, gram_form=self.gram_form,
+            gram_bytes=self.gram_bytes,
         ) as solve_span:
             with span("solve.rhs"):
                 bvecs = self.block_rhs(b)  # (J, p_pad, k); k=1 for one RHS

@@ -398,6 +398,59 @@ def _gram_coo(rows: np.ndarray, cols: np.ndarray, vals: np.ndarray):
     return ukey // p_span, ukey % p_span, summed
 
 
+def _tile_slots(rows: np.ndarray, cols: np.ndarray, bp: int, bn: int, n: int):
+    """The slot count ``S`` that ``_ell_arrays`` gives these entries of an
+    (m, n) matrix — the most distinct ``(bp, bn)`` tiles in one block-row,
+    at least 1 — counted without building the tiles."""
+    if rows.size == 0:
+        return 1
+    C = _ceil_div(n, bn)
+    tiles = np.unique((rows // bp) * C + cols // bn)
+    return int(np.bincount(tiles // C).max())
+
+
+def _dia_arrays(
+    grams: list[tuple[np.ndarray, np.ndarray, np.ndarray]],
+    offsets: np.ndarray,
+    p_pad: int,
+    dtype,
+) -> np.ndarray:
+    """Per-block Gram COOs -> diagonals (J, ndiag, p_pad): entry
+    ``[j, d, i]`` is G_j[i, i + offsets[d]], zero where the diagonal leaves
+    the block or the block has no entry there."""
+    diags = np.zeros((len(grams), offsets.size, p_pad), dtype)
+    for j, (gi, gj, gv) in enumerate(grams):
+        diags[j, np.searchsorted(offsets, gj - gi), gi] = gv
+    return diags
+
+
+# diagonals summed in one XLA fusion: past about a hundred static slices in
+# one fusion, the TPU compiler leaves the diagonals' broadcasts as device
+# ops of their own, one per diagonal and product
+_DIA_GROUP = 64
+
+
+def _dia_matmul(offsets: tuple[int, ...], diags, yt):
+    """Diagonal-form product with the rows on the last (lane) axis.
+
+    offsets (ndiag,) static, diags (J, ndiag, p), yt (J, k, p) -> (J, k, p):
+    ``out[j, :, i] = Σ_d diags[j, d, i] · yt[j, :, i + offsets[d]]``, each
+    term one static slice of the zero-padded operand and one elementwise
+    multiply-add, so nothing is gathered. The sum runs in groups of
+    ``_DIA_GROUP`` diagonals, one fusion each.
+    """
+    p = yt.shape[-1]
+    lo = max(0, -offsets[0]) if offsets else 0
+    hi = max(0, offsets[-1]) if offsets else 0
+    yp = jnp.pad(yt, ((0, 0), (0, 0), (lo, hi)))
+    out = jnp.zeros_like(yt)
+    for d, off in enumerate(offsets):
+        if d and d % _DIA_GROUP == 0:
+            out = jax.lax.optimization_barrier(out)
+        out = out + diags[:, d, None, :] * yp[..., lo + off: lo + off + p]
+    return out
+
+
 def _stack_shards(shards: list[tuple[np.ndarray, np.ndarray]]):
     """Pad per-shard ELL arrays to a common slot count and stack them."""
     S = max(idx.shape[1] for idx, _ in shards)
@@ -422,11 +475,15 @@ class PartitionedBSR:
     (``tra_*``, (J, Rn, T) tiles of (bn, bp)) for the Pallas kernel path,
     whose gather-driven DMA needs a contiguous streaming layout in both
     directions. ``with_gram=True`` stores the Gram operators
-    G_j = A_j A_jᵀ as (p, p) blocked-ELL shards (``gram_*``) — near-diagonal
-    for Schenk-like matrices, so they cost a few percent of the forward
-    shards and make each inner-CG iteration one SMALL SpMV instead of two
-    full ones. Blocks are padded to ``p_pad`` rows with zero rows
-    (consistent 0·x = 0 equations; see module docstring).
+    G_j = A_j A_jᵀ — near-diagonal for Schenk-like matrices, so they cost a
+    few percent of the forward shards and make each inner-CG iteration one
+    SMALL SpMV instead of two full ones — in one of two forms, chosen from
+    the Gram's own structure (``gram_form``): by their occupied diagonals
+    (``gram_offsets``, ``gram_diags``) when those store no more entries
+    than the ELL tiles would, as a stencil's Gram does, and otherwise as
+    (p, p) blocked-ELL shards (``gram_indices``, ``gram_data``). Blocks are
+    padded to ``p_pad`` rows with zero rows (consistent 0·x = 0 equations;
+    see module docstring).
 
     ``balance=True`` stores the forward/transpose tiles in a per-block
     balanced row order (``_balance_perm``): ``ext_pos[j, q]`` is the
@@ -444,6 +501,8 @@ class PartitionedBSR:
     tra_data: jnp.ndarray | None = None  # (J, Rn, T, bn, bp)
     gram_indices: jnp.ndarray | None = None  # (J, Rp, Sg) int32
     gram_data: jnp.ndarray | None = None  # (J, Rp, Sg, bp, bp)
+    gram_diags: jnp.ndarray | None = None  # (J, ndiag, p_pad)
+    gram_offsets: tuple[int, ...] | None = None  # (ndiag,) col − row, static
     ext_pos: jnp.ndarray | None = None  # (J, p_pad) int32: internal -> external
     int_pos: jnp.ndarray | None = None  # (J, p_pad) int32: external -> internal
     planned: bool = False  # built from a non-uniform PartitionPlan
@@ -465,8 +524,24 @@ class PartitionedBSR:
         """Device-resident bytes of the sparse operator (all present parts)."""
         arrs = (
             self.fwd_indices, self.fwd_data, self.tra_indices, self.tra_data,
-            self.gram_indices, self.gram_data, self.ext_pos, self.int_pos,
+            self.gram_indices, self.gram_data, self.gram_diags, self.ext_pos,
+            self.int_pos,
         )
+        return int(sum(a.nbytes for a in arrs if a is not None))
+
+    @property
+    def gram_form(self) -> str:
+        """How ``gram_mv`` applies G_j: "dia" (stored diagonals), "ell"
+        (stored blocked-ELL shards) or "none" (rmatvec, then matvec)."""
+        if self.gram_diags is not None:
+            return "dia"
+        return "ell" if self.gram_indices is not None else "none"
+
+    @property
+    def gram_bytes(self) -> int:
+        """Bytes of the stored Gram form that one ``gram_mv`` reads, over
+        all J blocks (0 when no Gram is stored)."""
+        arrs = (self.gram_indices, self.gram_data, self.gram_diags)
         return int(sum(a.nbytes for a in arrs if a is not None))
 
     @property
@@ -499,6 +574,12 @@ class PartitionedBSR:
         the tiles in a per-block load-balanced row order (the ELL slot
         count ``S`` is a max over block-rows; see ``_balance_perm``) while
         keeping every public product in the original row order.
+
+        The Gram shards are stored by their diagonals when the distinct
+        offsets ``col − row`` over all blocks number no more than the ELL
+        slots of a row (``ndiag ≤ Sg·bp``: the diagonal form stores no more
+        entries), and as blocked ELL otherwise — or when ``with_transpose``
+        asks for the Pallas kernel layout, which streams ELL tiles.
 
         ``plan`` (a ``repro.core.partition.PartitionPlan``) overrides the
         uniform contiguous row→block map: block heights become the plan's
@@ -601,24 +682,27 @@ class PartitionedBSR:
 
         # Gram shards stay in the EXTERNAL row order: the inner CG runs on
         # unpermuted vectors, so its hot loop never touches the permutation
-        gram_idx = gram_data = None
+        gram_idx = gram_data = gram_diags = gram_offsets = None
         if with_gram:
-            gram_idx, gram_data = _stack_shards(
-                [
-                    _ell_arrays(
-                        *_gram_coo(
-                            local[blk == j], cols[blk == j], coo.vals[blk == j]
-                        ),
-                        p_pad, p_pad, bp, bp, dtype,
-                    )
-                    for j in range(J)
-                ]
-            )
+            grams = [
+                _gram_coo(local[blk == j], cols[blk == j], coo.vals[blk == j])
+                for j in range(J)
+            ]
+            offsets = np.unique(np.concatenate([gj - gi for gi, gj, _ in grams]))
+            slots = max(_tile_slots(gi, gj, bp, bp, p_pad) for gi, gj, _ in grams)
+            if not with_transpose and offsets.size <= slots * bp:
+                gram_offsets = tuple(int(o) for o in offsets)
+                gram_diags = _dia_arrays(grams, offsets, p_pad, dtype)
+            else:
+                gram_idx, gram_data = _stack_shards(
+                    [_ell_arrays(*g, p_pad, p_pad, bp, bp, dtype) for g in grams]
+                )
 
         op = PartitionedBSR(
             fwd_idx, fwd_data, (m, n), p, p_pad,
             tra_indices=tra_idx, tra_data=tra_data,
             gram_indices=gram_idx, gram_data=gram_data,
+            gram_diags=gram_diags, gram_offsets=gram_offsets,
             ext_pos=ext_pos, int_pos=int_pos, planned=use_plan,
         )
         return op if host else jax.tree.map(jnp.asarray, op)
@@ -759,7 +843,17 @@ class PartitionedBSR:
 
     def gram_mv(self, y: jnp.ndarray, use_kernels: bool = False) -> jnp.ndarray:
         """(A_j A_jᵀ) y_j via the stored sparse Gram shards (or, without
-        them, as rmatvec-then-matvec): (J, p_pad, k) -> (J, p_pad, k)."""
+        them, as rmatvec-then-matvec): (J, p_pad, k) -> (J, p_pad, k).
+
+        The diagonal form runs with the rows on the last axis, the TPU's
+        128-wide lanes, and the k columns on its sublanes, so no shifted
+        read pads a narrow k. XLA lays the operands out that way, so the
+        two transposes cost no copy. It has no gather for a kernel to
+        stream, so it runs the same under ``use_kernels``."""
+        if self.gram_diags is not None:
+            yt = jnp.swapaxes(y, 1, 2)
+            out = _dia_matmul(self.gram_offsets, self.gram_diags, yt)
+            return jnp.swapaxes(out, 1, 2)
         if self.gram_indices is None:
             return self.matvec(self.rmatvec(y, use_kernels), use_kernels)
         bp = self.block_shape[0]
@@ -769,6 +863,30 @@ class PartitionedBSR:
 
             return spmm_ops.spmm(self.gram_indices, self.gram_data, yb)
         return _ell_matmul_stacked(self.gram_indices, self.gram_data, yb)
+
+    def gram_dense(self) -> np.ndarray:
+        """The stored Gram shards as host float64 (J, p_pad, p_pad), from
+        whichever form is stored (the direct solver's pseudo-inverse input)."""
+        J, p_pad = self.num_blocks, self.p_pad
+        out = np.zeros((J, p_pad, p_pad))
+        if self.gram_diags is not None:
+            diags = np.asarray(self.gram_diags, np.float64)
+            rows = np.arange(p_pad)
+            for d, off in enumerate(self.gram_offsets):
+                i = rows[max(0, -off): p_pad - max(0, off)]
+                out[:, i, i + off] = diags[:, d, i]
+            return out
+        idx = np.asarray(self.gram_indices)
+        data = np.asarray(self.gram_data, np.float64)
+        _, Rp, Sg = idx.shape
+        bp = data.shape[-2]
+        for j in range(J):
+            G = np.zeros((Rp, Rp, bp, bp))
+            # padding slots target block 0 with zero data: += keeps them inert
+            np.add.at(G, (np.repeat(np.arange(Rp), Sg), idx[j].ravel()),
+                      data[j].reshape(Rp * Sg, bp, bp))
+            out[j] = G.transpose(0, 2, 1, 3).reshape(p_pad, p_pad)
+        return out
 
     def gram_diag(self) -> jnp.ndarray:
         """diag(A_j A_jᵀ) per block — (J, p_pad) row sums of squares, the
@@ -814,7 +932,8 @@ class PartitionedBSR:
         The split is DERIVED from the dataclass fields: every array child
         (present ones only — absent transpose/gram/balance parts are simply
         omitted) lands in ``arrays`` under ``prefix + field_name``, and the
-        static shape metadata lands in ``meta``. ``from_arrays`` inverts it
+        static metadata (shapes, the Gram diagonals' offsets) lands in
+        ``meta``. ``from_arrays`` inverts it
         bit-for-bit — the restored operator's products are identical.
         """
         arrays: dict = {}
@@ -825,6 +944,9 @@ class PartitionedBSR:
                 meta[f.name] = bool(value)
             elif f.name in ("shape", "p", "p_pad"):
                 meta[f.name] = list(value) if f.name == "shape" else int(value)
+            elif f.name == "gram_offsets":
+                if value is not None:
+                    meta[f.name] = list(value)
             elif value is not None:
                 arrays[prefix + f.name] = np.asarray(value)
         return arrays, meta
@@ -838,9 +960,11 @@ class PartitionedBSR:
             for f in dataclasses.fields(cls)
             if prefix + f.name in arrays
         }
+        offsets = meta.get("gram_offsets")
         return cls(
             shape=tuple(meta["shape"]), p=int(meta["p"]),
             p_pad=int(meta["p_pad"]),
+            gram_offsets=None if offsets is None else tuple(offsets),
             planned=bool(meta.get("planned", False)), **kwargs,
         )
 
@@ -871,21 +995,22 @@ class PartitionedBSR:
 def _bsr_flatten(op: PartitionedBSR):
     children = (
         op.fwd_indices, op.fwd_data, op.tra_indices, op.tra_data,
-        op.gram_indices, op.gram_data, op.ext_pos, op.int_pos,
+        op.gram_indices, op.gram_data, op.gram_diags, op.ext_pos, op.int_pos,
     )
-    return children, (op.shape, op.p, op.p_pad, op.planned)
+    return children, (op.shape, op.p, op.p_pad, op.gram_offsets, op.planned)
 
 
 def _bsr_unflatten(aux, children):
-    shape, p, p_pad, planned = aux
+    shape, p, p_pad, gram_offsets, planned = aux
     (
         fwd_idx, fwd_data, tra_idx, tra_data, gram_idx, gram_data,
-        ext_pos, int_pos,
+        gram_diags, ext_pos, int_pos,
     ) = children
     return PartitionedBSR(
         fwd_idx, fwd_data, shape=shape, p=p, p_pad=p_pad,
         tra_indices=tra_idx, tra_data=tra_data,
         gram_indices=gram_idx, gram_data=gram_data,
+        gram_diags=gram_diags, gram_offsets=gram_offsets,
         ext_pos=ext_pos, int_pos=int_pos, planned=planned,
     )
 

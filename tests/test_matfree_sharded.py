@@ -283,6 +283,88 @@ def test_multi_device_capped_pcg_subprocess(hpcg, tmp_path):
     assert out.stdout.count(" OK ") == len(HPCG_CAPPED)
 
 
+MULTI_DEVICE_DIA_SCRIPT = textwrap.dedent(
+    """
+    import dataclasses, os, sys
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+    import jax, numpy as np
+    from repro.core import prepare
+    from repro.sparse import COOMatrix
+    from repro.sparse.bsr import PartitionedBSR
+
+    assert jax.device_count() == 4, jax.device_count()
+    mesh = jax.make_mesh((4,), ("data",))
+    tol, epochs = float(sys.argv[2]), int(sys.argv[3])
+    a = np.load(sys.argv[1])
+    B = a["B"]
+    n, k = B.shape
+    coo = COOMatrix(a["rows"], a["cols"], a["vals"], (n, n))
+    sh = prepare(coo, mesh=mesh, mode="matfree", num_blocks=8, gamma=2.0,
+                 eta=1.9, balance=False, gram_solver="pcg")
+    assert sh.gram_form == "dia", sh.gram_form
+    assert sh.gram_bytes == sh.op.gram_diags.nbytes // 4
+    # the same solver on the ELL Gram shards, placed the same way
+    ell_op = PartitionedBSR.from_coo(coo, 8, with_gram=True,
+                                     with_transpose=True, host=True)
+    ell_op = dataclasses.replace(ell_op, tra_indices=None, tra_data=None)
+    ell = dataclasses.replace(sh, op=ell_op.place(mesh, ("data",)),
+                              _jit_cache={})
+    assert ell.gram_form == "ell" and ell.gram_bytes > sh.gram_bytes
+    r_dia = sh.solve(B.astype(np.float32), num_epochs=epochs, tol=tol)
+    r_ell = ell.solve(B.astype(np.float32), num_epochs=epochs, tol=tol)
+    its_dia = r_dia.iterations_to_tol(tol)
+    its_ell = r_ell.iterations_to_tol(tol)
+    assert (its_dia < epochs).all() and (its_ell < epochs).all()
+    slip = np.abs(its_dia - its_ell)
+    assert slip.max() <= 1, (its_dia, its_ell)
+    # float64 residual of the whole system, in units of tol (min ||b|| = 1)
+    Ax = np.zeros((n, k))
+    np.add.at(Ax, a["rows"], a["vals"][:, None] * r_dia.x[a["cols"]])
+    resid = float(np.linalg.norm(Ax - B, axis=0).max() / tol)
+    assert resid <= 1.05, resid
+    print("dia OK epochs", its_dia.max(), "ell", its_ell.max(), "resid", resid)
+    """
+)
+
+
+def test_multi_device_dia_gram_subprocess(hpcg, tmp_path):
+    """On a 4-device CPU mesh, HPCG's 8×8×16 stencil takes the diagonal
+    Gram form: the capped PCG reaches tol within one epoch of the ELL
+    form, and the answer passes the float64 residual check."""
+    coo, _, B = hpcg(8, 8, 16)
+    np.savez(tmp_path / "case.npz", rows=coo.rows, cols=coo.cols,
+             vals=coo.vals, B=B)
+    env = dict(os.environ)
+    env["PYTHONPATH"] = "src"
+    out = subprocess.run(
+        [sys.executable, "-c", MULTI_DEVICE_DIA_SCRIPT,
+         str(tmp_path / "case.npz"), str(HPCG_TOL), str(HPCG_EPOCHS)],
+        capture_output=True, text=True, env=env,
+        cwd=os.path.dirname(os.path.dirname(__file__)), timeout=600,
+    )
+    assert out.returncode == 0, f"stdout:\n{out.stdout}\nstderr:\n{out.stderr}"
+    assert "dia OK" in out.stdout
+
+
+@pytest.mark.parametrize("mesh", [False, True])
+@pytest.mark.parametrize(
+    "gram_solver, form", [("pcg", "dia"), ("direct", "none")]
+)
+def test_solve_span_reports_gram_form(hpcg, profile, mesh, gram_solver, form):
+    """``repro.solve`` carries ``gram_form`` and ``gram_bytes``: the
+    stored diagonals' bytes on the PCG path, none on the direct path."""
+    coo, _, B = hpcg(8, 8, 16)
+    kw = dict(HPCG_KW, gram_solver=gram_solver)
+    prep = prepare(coo, mesh=_mesh1() if mesh else None, **kw)
+    with profile() as events:
+        prep.solve(B.astype(np.float32), num_epochs=5)
+    (stats,) = [e[3] for e in events if e[0] == "repro.solve"]
+    assert stats["gram_form"] == prep.gram_form == form
+    assert stats["gram_bytes"] == prep.gram_bytes
+    want = prep.op.gram_diags.nbytes if form == "dia" else 0
+    assert prep.gram_bytes == want
+
+
 MULTI_DEVICE_MATFREE_SCRIPT = textwrap.dedent(
     """
     import os

@@ -371,6 +371,7 @@ def test_matfree_solve_spans_under_profiler(problem, profile):
     ]
     assert events[0][3] == {
         "path": "matfree", "k": B.shape[1], "num_epochs": 300,
+        "gram_form": "none", "gram_bytes": 0,
         "epochs_run": traced.epochs_run, "gram_iters": traced.gram_iters,
     }
     assert traced.gram_iters == traced.epochs_run  # direct Gram (auto)

@@ -10,7 +10,12 @@ import numpy as np
 import pytest
 
 from repro.sparse import COOMatrix, generate_schenk_like
-from repro.sparse.bsr import BlockEll, PartitionedBSR
+from repro.sparse.bsr import (
+    BlockEll,
+    PartitionedBSR,
+    _ell_matmul_stacked,
+    _pad_cols,
+)
 from repro.testing import given, settings, st
 
 
@@ -328,3 +333,98 @@ def test_jacobi_weights_relative_clamp():
     # clamp: bounded by 1 / (max_diag * eps) instead of 1 / 1e-36
     assert w[1] <= 1.0 / (diag.max() * 1e-10) * (1 + 1e-6)
     np.testing.assert_array_equal(w[2:], 0.0)  # padding rows stay pinned
+
+
+# -- Gram form: diagonals or blocked ELL, chosen from the Gram's structure --
+
+
+def _hpcg_coo(nx, ny, nz):
+    from conftest import hpcg_stencil
+
+    A = hpcg_stencil(nx, ny, nz).tocoo()
+    return COOMatrix(
+        A.row.astype(np.int32), A.col.astype(np.int32), A.data, A.shape
+    )
+
+
+GRAM_CASES = {
+    # HPCG z-slab blocks: two end blocks and an interior one
+    "hpcg_slabs": (lambda: _hpcg_coo(6, 6, 12), 3, "dia"),
+    # 214-row blocks padded to 216 (the last holds 212 rows)
+    "hpcg_padded": (lambda: _hpcg_coo(8, 8, 10), 3, "dia"),
+    # an unstructured matrix: its offsets outnumber the ELL slots of a row
+    "random": (lambda: _random_coo(96, 96, density=0.08, seed=3), 2, "ell"),
+}
+
+
+@pytest.mark.parametrize("k", [1, 8])
+@pytest.mark.parametrize("case", sorted(GRAM_CASES))
+def test_gram_form_products(case, k):
+    """The stored Gram form follows the matrix: a stencil's Gram goes by
+    its diagonals and matches the ELL product and a float64 A_j A_jᵀ y; an
+    unstructured Gram keeps the ELL shards the parent built, and their
+    product bit for bit."""
+    make, J, form = GRAM_CASES[case]
+    coo = make()
+    op = PartitionedBSR.from_coo(coo, J, (8, 8), with_gram=True)
+    # the kernel layout always streams ELL tiles: the parent's Gram shards
+    ell = PartitionedBSR.from_coo(
+        coo, J, (8, 8), with_gram=True, with_transpose=True
+    )
+    assert op.gram_form == form and ell.gram_form == "ell"
+    if case == "hpcg_padded":
+        assert op.p_pad > op.p
+    rng = np.random.default_rng(k)
+    y = jnp.asarray(
+        rng.standard_normal((J, op.p_pad, k)).astype(np.float32)
+    )
+    blocks = _dense_blocks(coo, J, op.p, op.p_pad, dtype=np.float64)
+    want = np.einsum(
+        "jpn,jqn,jqk->jpk", blocks, blocks, np.asarray(y, np.float64)
+    )
+    got = np.asarray(op.gram_mv(y), np.float64)
+    scale = np.abs(want).max()
+    assert np.abs(got - want).max() <= 1e-6 * scale
+    np.testing.assert_array_equal(op.gram_dense(), ell.gram_dense())
+    if form == "dia":
+        assert len(op.gram_offsets) <= ell.gram_indices.shape[-1] * 8
+        assert op.gram_bytes < ell.gram_bytes
+        ell_out = np.asarray(ell.gram_mv(y), np.float64)
+        assert np.abs(got - ell_out).max() <= 1e-6 * scale
+    else:
+        assert op.gram_offsets is None and op.gram_diags is None
+        np.testing.assert_array_equal(op.gram_indices, ell.gram_indices)
+        np.testing.assert_array_equal(op.gram_data, ell.gram_data)
+        yb = jax.vmap(lambda v: _pad_cols(v, op.p_pad, 8))(y)
+        np.testing.assert_array_equal(
+            np.asarray(op.gram_mv(y)),
+            np.asarray(_ell_matmul_stacked(op.gram_indices, op.gram_data, yb)),
+        )
+
+
+def test_dia_gram_roundtrips():
+    """to_arrays/from_arrays and a pytree round trip keep the diagonal
+    form and its static offsets; as a jit operand the rebuilt operator
+    gives the original's product bit for bit."""
+    op = PartitionedBSR.from_coo(_hpcg_coo(6, 6, 12), 3, with_gram=True)
+    assert op.gram_form == "dia"
+    y = jnp.asarray(
+        np.random.default_rng(0).standard_normal((3, op.p_pad, 4)),
+        jnp.float32,
+    )
+    def product(o):
+        return jax.jit(lambda o, v: o.gram_mv(v))(o, y)
+
+    want = np.asarray(product(op))
+    np.testing.assert_allclose(
+        np.asarray(op.gram_mv(y)), want, rtol=1e-6, atol=1e-6 * np.abs(want).max()
+    )
+    arrays, meta = op.to_arrays()
+    assert "op_gram_offsets" not in arrays and "op_gram_diags" in arrays
+    restored = PartitionedBSR.from_arrays(arrays, meta)
+    leaves, treedef = jax.tree_util.tree_flatten(op)
+    rebuilt = jax.tree_util.tree_unflatten(treedef, leaves)
+    for back in (restored, rebuilt):
+        assert back.gram_form == "dia"
+        assert back.gram_offsets == op.gram_offsets
+        np.testing.assert_array_equal(np.asarray(product(back)), want)

@@ -137,3 +137,37 @@ def test_dense_solve_reads_the_factor_as_stored(one_chip):
     assert not re.search(moved, text)
     assert f"f32[{J},{N},{P_PAD}]" not in text  # no Wᵀ anywhere
     assert f"f32[{J},{N},{N}]" not in text  # no materialized P_j
+
+
+def test_dia_gram_pcg_compiles_without_gather(one_chip):
+    """The capped Gram PCG on the diagonal Gram form, at one chip's share
+    of the four-chip HPCG cell (two 24×24×8 z-slab blocks of p = 4608
+    rows, 125 diagonals, k = 8): no gather, almost no temporaries (the ELL
+    form needs hundreds of MB), and no diagonal's broadcast left as a
+    device op of its own in the loop body."""
+    from repro.core.matfree import _pcg_gram
+    from repro.sparse.bsr import PartitionedBSR
+
+    jb, p = 2, 4608
+    offsets = tuple(sorted(
+        dx + 24 * dy + 576 * dz
+        for dx in range(-2, 3) for dy in range(-2, 3) for dz in range(-2, 3)
+    ))
+    op = PartitionedBSR(
+        _shape(one_chip, (jb, p // TILE, 1), jnp.int32),
+        _shape(one_chip, (jb, p // TILE, 1, TILE, TILE)),
+        (jb * p, jb * p), p, p,
+        gram_diags=_shape(one_chip, (jb, len(offsets), p)),
+        gram_offsets=offsets,
+    )
+    compiled = jax.jit(
+        lambda o, r, d: _pcg_gram(o, r, d, 32, 1e-6, False)
+    ).lower(
+        op, _shape(one_chip, (jb, p, K)), _shape(one_chip, (jb, p, 1))
+    ).compile()
+    text = compiled.as_text()
+    assert " while(" in text and " gather(" not in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 4 * 2**20
+    body = re.search(r"body=%([\w.\-]+)", text).group(1)
+    start = text.index(f"%{body} ")
+    assert " broadcast(" not in text[start: text.index("\n}\n", start)]
